@@ -59,27 +59,21 @@ from .. import telemetry
 from ..ops.reducers import DTYPE_ENUM, OP_NAMES
 
 
-def _experimental_enable_x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
-
-
 def _require_private_api():
     """The data plane rides jaxlib private APIs
     (``jax._src.distributed.global_state`` plus the distributed-runtime
     client), necessary because the public ``jax.distributed.initialize``
     client LOG(FATAL)s the process on peer death (see module
-    docstring). The client bindings moved between jax 0.4.x and 0.9.x;
-    ``utils/jaxcompat.py`` owns the probe and kwarg translation. Check
-    at construction — a jax upgrade that removed them must fail loudly
-    here, not mid-recovery (VERDICT r2 weak #7)."""
+    docstring); ``utils/jaxcompat.py`` owns the private import and
+    kwargs. Check at construction — a jax upgrade that removed them
+    must fail loudly here, not mid-recovery (VERDICT r2 weak #7)."""
     try:
         from jax._src.distributed import global_state  # noqa: F401
     except ImportError as e:
         raise RuntimeError(
             "rabit_tpu's XLA data plane requires jax private modules "
-            "(jax._src.distributed) — verified against jax 0.4.x and "
-            "0.9.x; this jax build lacks them") from e
+            "(jax._src.distributed) — verified against jax 0.9.0; "
+            "this jax build lacks them") from e
     from ..utils import jaxcompat
     jaxcompat.distributed_runtime_module()
 
@@ -197,10 +191,9 @@ class XlaDataPlane:
             # zombie, and whenever its (reaped or stopping) service
             # cancels the poll, client.h LOG(FATAL)s this process.
             # client.shutdown() cancels the poll and returns promptly —
-            # recoverable tasks skip the peer barrier, and on jaxlibs
-            # without the recoverable flag the 1s shutdown_timeout
-            # (utils/jaxcompat.py) bounds it; the tracker-hosted service
-            # it talks to outlives every worker by design.
+            # recoverable tasks skip the peer barrier; the
+            # tracker-hosted service it talks to outlives every worker
+            # by design.
             try:
                 client.shutdown()
             except Exception as e:  # noqa: BLE001 - service may be gone
@@ -258,13 +251,13 @@ class XlaDataPlane:
         # failure the robust engine exists to absorb. A Python
         # missed_heartbeat_callback is no escape: invoking it aborts via
         # std::bad_cast in this jaxlib.
-        # recoverable=True (where this jaxlib has it) is load-bearing:
+        # recoverable=True is load-bearing:
         # it marks the task recoverable in the coordination service,
         # which then does NOT propagate this task's disconnect as a
         # fatal error to peers still polling — without it, any
         # non-simultaneous client teardown (recovery, staggered process
-        # exit) LOG(FATAL)s the laggards. jaxcompat translates the
-        # kwargs per jaxlib generation and connects.
+        # exit) LOG(FATAL)s the laggards. jaxcompat builds and
+        # connects the client.
         client = jaxcompat.connect_client(addr, self._rank,
                                           self._init_timeout)
         global_state.client = client
@@ -433,11 +426,8 @@ class XlaDataPlane:
             or "off",
             round=telemetry.collective_round("dataplane.allreduce"))
         # 64-bit payloads: without x64 device_put truncates to 32 bits
-        # (jax.enable_x64 is the >=0.9 spelling; 0.4.x has the same
-        # context manager under jax.experimental)
         if buf.dtype.itemsize == 8:
-            ctx = (jax.enable_x64(True) if hasattr(jax, "enable_x64")
-                   else _experimental_enable_x64())
+            ctx = jax.enable_x64(True)
         else:
             ctx = contextlib.nullcontext()
         with sp, ctx:

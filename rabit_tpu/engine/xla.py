@@ -41,11 +41,6 @@ from ..utils.log import log_debug
 from ..utils.watchdog import Watchdog
 
 
-def _experimental_enable_x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
-
-
 class XlaEngine(Engine):
     def __init__(self) -> None:
         self._rank = 0
@@ -292,11 +287,8 @@ class XlaEngine(Engine):
         mesh = self._mesh
         # 64-bit payloads: without x64, device_put silently truncates
         # int64/float64 to 32 bits; scope-enable it for this reduction
-        # (jax.enable_x64 is the >=0.9 spelling; older jax has the same
-        # context manager under jax.experimental).
         if buf.dtype.itemsize == 8:
-            ctx = (jax.enable_x64(True) if hasattr(jax, "enable_x64")
-                   else _experimental_enable_x64())
+            ctx = jax.enable_x64(True)
         else:
             ctx = contextlib.nullcontext()
         with ctx:
@@ -491,8 +483,7 @@ class XlaEngine(Engine):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
         if buf.dtype.itemsize == 8:
-            ctx = (jax.enable_x64(True) if hasattr(jax, "enable_x64")
-                   else _experimental_enable_x64())
+            ctx = jax.enable_x64(True)
         else:
             ctx = contextlib.nullcontext()
         mesh = self._mesh

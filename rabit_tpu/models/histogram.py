@@ -29,6 +29,15 @@ from ..parallel.collectives import (
     RING_MINCOUNT_DEFAULT)
 
 
+def resolve_method(method: str) -> str:
+    """The build method ``method="auto"`` stands for on this backend:
+    the compiled Pallas kernel on a TPU, ``scatter`` elsewhere."""
+    if method != "auto":
+        return method
+    from ..ops.pallas_kernels import pallas_available
+    return "pallas" if pallas_available() else "scatter"
+
+
 def local_histogram(grad: jax.Array, hess: jax.Array, bins: jax.Array,
                     nbins: int, method: str = "auto",
                     precision: str = "high") -> jax.Array:
@@ -41,9 +50,7 @@ def local_histogram(grad: jax.Array, hess: jax.Array, bins: jax.Array,
     the pallas accumulation: "high" (default, ~f32 accuracy) or "fast"
     (single bf16 dot, ~2e-4 rel err — an explicit perf opt-in).
     """
-    if method == "auto":
-        from ..ops.pallas_kernels import pallas_available
-        method = "pallas" if pallas_available() else "scatter"
+    method = resolve_method(method)
     if method == "pallas":
         from ..ops.pallas_kernels import histogram_tpu, _CHUNK
         n = grad.shape[0]
@@ -113,11 +120,8 @@ def distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
     # so does the pallas kernel (pallas_call's interpret evaluator is
     # vma-inconsistent across its trace passes); matmul/scatter over the
     # psum tree run fully checked
-    resolved = method
-    if method == "auto":
-        from ..ops.pallas_kernels import pallas_available
-        resolved = "pallas" if pallas_available() else "scatter"
-    sm = (unchecked_shard_map if use_ring or resolved == "pallas"
+    sm = (unchecked_shard_map
+          if use_ring or resolve_method(method) == "pallas"
           else shard_map)
     return sm(per_shard, mesh=mesh,
               in_specs=(P(axis), P(axis), P(axis)),

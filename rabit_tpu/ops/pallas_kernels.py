@@ -51,18 +51,12 @@ _ATILE = 512    # high-level bin groups per grid step (VMEM bound)
 def _out_struct(shape, dtype, *arrs):
     """``ShapeDtypeStruct`` whose varying-manual-axes (vma) is the union
     of the inputs' — required for pallas_call outputs under a
-    ``check_vma=True`` shard_map (jax >= 0.7 tracks vma through avals);
-    a plain struct elsewhere."""
+    ``check_vma=True`` shard_map; a plain struct elsewhere."""
     vma = set()
     for a in arrs:
-        v = getattr(jax.typeof(a), "vma", None)
-        if v:
-            vma |= set(v)
+        vma |= set(jax.typeof(a).vma)
     if vma:
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
-        except TypeError:  # pragma: no cover - older jax without vma kw
-            pass
+        return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
@@ -319,13 +313,17 @@ def _flash_bwd_body(has_mask, sm_scale, *refs):
 
 
 def _flash_compiler_params():
-    """Mosaic params for both flash block kernels. At the ring chain's
-    block sizes (T = S = 1024, D = 128) the backward holds ~5 [T, S]
-    f32 temporaries (s, p, dp, ds, tie mask) — past the default 16 MB
-    *scoped* vmem budget on v5e, the same overflow that kept the
-    histogram kernel's fused path from compiling (see
-    _hist_compiler_params). The head grid axis writes disjoint
-    per-head blocks (parallel)."""
+    """Mosaic params for both flash block kernels. The backward holds ~5
+    [T, S] f32 temporaries (s, p, dp, ds, tie mask) per head — past the
+    default 16 MB *scoped* vmem budget on v5e at T = S = 1024, the same
+    overflow that kept the histogram kernel's fused path from compiling
+    (see _hist_compiler_params). With the 64 MB limit below, compiling
+    for a described v5e (H = 8, D = 128) shows the limit: T = S = 1024
+    is the largest block shown to compile with the fused backward; at
+    2048 the backward runs out of VMEM (RESOURCE_EXHAUSTED) while the
+    forward still compiles. Longer sequences need smaller blocks (more
+    ring steps), not a larger budget. The head grid axis writes
+    disjoint per-head blocks (parallel)."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
         dimension_semantics=("parallel",),

@@ -73,15 +73,7 @@ from . import topology as _topology
 from .dispatch import (RING_MINCOUNT_DEFAULT,  # noqa: F401  (re-export)
                        WIRE_MINCOUNT_DEFAULT, resolve as _dispatch_resolve)
 
-try:  # jax>=0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-import inspect as _inspect
-
-# param renamed check_rep -> check_vma across jax versions
-_CHECK_KW = ("check_vma" if "check_vma" in
-             _inspect.signature(_shard_map).parameters else "check_rep")
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, **kwargs):
@@ -90,7 +82,7 @@ def shard_map(f, **kwargs):
     The checker statically verifies that values declared replicated
     (``P()`` out_specs) really are, catching the double-psum bug class
     ``psum_identity_grad``'s docstring describes."""
-    kwargs.setdefault(_CHECK_KW, True)
+    kwargs.setdefault("check_vma", True)
     return _shard_map(f, **kwargs)
 
 
@@ -101,19 +93,14 @@ def unchecked_shard_map(f, **kwargs):
     protocol, which the static checker cannot infer through a ppermute
     chain. Scope of use is exactly those bodies; everything else goes
     through :func:`shard_map`."""
-    kwargs.setdefault(_CHECK_KW, False)
+    kwargs.setdefault("check_vma", False)
     return _shard_map(f, **kwargs)
 
 def axis_size(axis_name) -> int:
-    """Static size of the named mesh axis, as a Python int.
-
-    ``lax.axis_size`` where this jax has it; otherwise ``psum`` of the
-    literal 1, which jax constant-folds to the axis size without
-    emitting a collective. Every Python-level schedule below (ring step
-    counts, Swing tables) needs this as a concrete loop bound."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of the named mesh axis, as a Python int. Every
+    Python-level schedule below (ring step counts, Swing tables) needs
+    this as a concrete loop bound."""
+    return lax.axis_size(axis_name)
 
 
 def _ring_perm(p: int, reverse: bool = False):
@@ -1636,7 +1623,7 @@ class AsyncHandle:
             return True
         is_ready = getattr(self._out, "is_ready", None)
         if is_ready is None:
-            # pytree or older jaxlib: no cheap readiness probe
+            # pytree: no cheap readiness probe on the container
             leaves = jax.tree_util.tree_leaves(self._out)
             return all(getattr(leaf, "is_ready", lambda: False)()
                        for leaf in leaves)

@@ -26,6 +26,7 @@ import os
 import re
 from typing import Optional, Tuple
 
+from ..utils import log
 from ..utils.config import parse_size
 from . import wire as _wirespec
 
@@ -198,9 +199,10 @@ def _valid_rows(rows) -> bool:
     return rows[-1].get("max_n") is None  # must cover every size
 
 
-# path -> (mtime, table-or-None); a changed file re-parses, a bad file
-# is remembered as bad until it changes
+# path -> (mtime, table-or-None, source); a changed file re-parses, a
+# bad file is remembered as bad until it changes
 _cache: dict = {}
+_source = "documented constants (no table loaded yet)"
 
 
 def clear_cache() -> None:
@@ -225,24 +227,30 @@ def load_table(path: Optional[str] = None) -> Optional[dict]:
     ``COLLECTIVE_SWEEP_*.json`` under ``benchmarks/artifacts/`` (repo
     root also scanned for compatibility). A missing file, a schema
     outside ``ACCEPTED_SCHEMAS`` (v2 and the legacy v1 — future majors
-    must not be misread), or malformed rows all yield None — dispatch
-    must degrade to the documented defaults, never crash.
+    must not be misread), malformed rows, or a table whose recorded
+    ``backend`` is not the running one all yield None — dispatch then
+    uses the documented defaults, and :func:`table_source` says why.
     """
+    global _source
     if path is None:
         env = os.environ.get(_TABLE_ENV)
         if env in ("none", "off", "0"):
+            _source = f"documented constants ({_TABLE_ENV}={env})"
             return None
         path = env or _newest_sweep()
     if not path:
+        _source = "documented constants (no table found)"
         return None
     try:
         mtime = os.path.getmtime(path)
     except OSError:
+        _source = f"documented constants ({path} missing)"
         return None
     from ..telemetry import profile
     hit = _cache.get(path)
     if hit is not None and hit[0] == mtime:
         profile.cache_event("dispatch_table", hit=True)
+        _source = hit[2]
         return hit[1]
     profile.cache_event("dispatch_table", hit=False)
     table = None
@@ -257,8 +265,29 @@ def load_table(path: Optional[str] = None) -> Optional[dict]:
                 table = cand
     except (OSError, ValueError):
         table = None
-    _cache[path] = (mtime, table)
+    if table is None:
+        _source = f"documented constants ({path} unreadable or invalid)"
+    else:
+        import jax
+        running, measured = jax.default_backend(), data.get("backend")
+        if measured != running:
+            # a table timed on another fabric (every committed sweep
+            # ran on the 8-device CPU mesh) must not steer this one
+            table = None
+            _source = (f"documented constants ({path} was measured on "
+                       f"backend {measured!r}, running {running!r})")
+        else:
+            _source = f"table {path} (backend {running!r})"
+    log.log_info("dispatch: %s", _source)
+    _cache[path] = (mtime, table, _source)
     return table
+
+
+def table_source() -> str:
+    """Which source the last :func:`load_table` decision used: a table
+    measured on the running backend, or the documented constants and
+    why."""
+    return _source
 
 
 def _bucket(rows, n: int) -> dict:

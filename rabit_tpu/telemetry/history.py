@@ -69,7 +69,7 @@ for _lvl in (0, 1000, 5000, 10000):
 _NON_CONFIG_KEYS = frozenset({
     "value", "vs_baseline", "correct", "timestamp_utc", "t_dev_ms",
     "t_host_ms", "gbps", "bandwidth_vs_rows", "losses", "rows", "table",
-    "counters", "spans", "tpu", "cpu", "status", "cached_from",
+    "counters", "spans", "tpu", "cpu", "status",
     "best_step_s", "compile_plus_first_step_s", "complete",
     "bounded_threads", "max_idle_conns",
 })

@@ -8,7 +8,7 @@ What it records, all host-side:
 
 - **jit probes** (``jit_probe(tag, fn)``): wrap a call to a jitted
   function; the probe reads the function's compilation-cache size
-  before and after (``fn._cache_size()``, available on jax 0.4 jitted
+  before and after (``fn._cache_size()`` of jax's jitted
   wrappers). Cache growth means this call paid trace+compile — the
   elapsed wall time is recorded as a compile sample under ``tag`` and a
   cache *miss*; no growth is a cache *hit*. Functions without the

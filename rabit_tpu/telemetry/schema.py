@@ -6,8 +6,8 @@ prefix), telemetry summaries/traces, and tooling status reports
 route any artifact by its ``schema`` field and reject foreign majors
 without guessing at ad-hoc fields.
 
-Stdlib-only on purpose: the tracker and the tunnel-watcher tooling
-import this without pulling jax/numpy.
+Stdlib-only on purpose: the tracker and the status tooling import this
+without pulling jax/numpy.
 """
 
 from __future__ import annotations

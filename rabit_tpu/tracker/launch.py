@@ -222,6 +222,28 @@ class _TrackerSupervisor:
                   flush=True)
 
 
+def _xla_dataplane_requested(cmd: List[str]) -> bool:
+    return (os.environ.get("RABIT_DATAPLANE") == "xla"
+            or any(a == "rabit_dataplane=xla" for a in cmd))
+
+
+def _refuse_shared_chip(nworkers: int, xla_plane: bool) -> None:
+    """Refuse to start xla-data-plane workers that would share a chip.
+    The launcher pins no chip per worker, so unless the workers are
+    held off the TPU (``JAX_PLATFORMS=cpu``) every one of them would
+    claim every chip of the host. A multi-process device world on
+    several chips has never run (ROADMAP)."""
+    platforms = [p for p in os.environ.get("JAX_PLATFORMS", "").split(",")
+                 if p]
+    if xla_plane and nworkers > 1 and (not platforms
+                                       or "tpu" in platforms):
+        raise RuntimeError(
+            f"refusing to launch {nworkers} rabit_dataplane=xla workers "
+            "that would share the host's chips: this launcher pins no "
+            "chip per worker. Set JAX_PLATFORMS=cpu to run the workers "
+            "on the CPU backend.")
+
+
 def launch(nworkers: int, cmd: List[str], max_attempts: int = 20,
            timeout: float = 300.0, quiet: bool = False,
            coordinator: Optional[bool] = None,
@@ -246,8 +268,8 @@ def launch(nworkers: int, cmd: List[str], max_attempts: int = 20,
     registration and collective traffic (doc/fault_tolerance.md)."""
     from . import membership as _membership
     if coordinator is None:
-        coordinator = (os.environ.get("RABIT_DATAPLANE") == "xla"
-                       or any(a == "rabit_dataplane=xla" for a in cmd))
+        coordinator = _xla_dataplane_requested(cmd)
+    _refuse_shared_chip(nworkers, coordinator)
     if chaos is None:
         chaos = os.environ.get("RABIT_CHAOS") or None
     if elastic is None:
@@ -461,6 +483,7 @@ def submit_launch(addr: str, job_id: str, nworkers: int, cmd: List[str],
     owned here: its lifecycle (and any chaos/standby fronting) belongs
     to whoever started it."""
     from . import jobs as _jobs_mod
+    _refuse_shared_chip(nworkers, _xla_dataplane_requested(cmd))
     host, _, port_s = addr.rpartition(":")
     if not host or not port_s.isdigit():
         print(f"[submit] bad --submit address {addr!r} "

@@ -1,55 +1,31 @@
-"""Version adapters for jaxlib's private distributed-runtime bindings.
+"""Adapters for jaxlib's private distributed-runtime bindings.
 
 The device plane and the tracker's device-world coordinator ride the
 *private* distributed runtime (service + client) because the public
 ``jax.distributed.initialize`` client LOG(FATAL)s the whole process on
 peer death — exactly the failure the robust engine exists to absorb
-(engine/dataplane.py module docstring). Private APIs move between
-releases:
+(engine/dataplane.py module docstring). jax 0.9 exposes the bindings at
+``jax._src.lib._jax``; the client's ``recoverable`` flag stops the
+service from propagating one task's disconnect to its peers.
 
-- jax >= 0.9 exposes the bindings at ``jax._src.lib._jax`` and spells
-  the liveness knob ``heartbeat_timeout``; the client grows a
-  ``recoverable`` flag that stops the service from propagating one
-  task's disconnect to its peers.
-- jax 0.4.x exposes the same functions at
-  ``jax._src.lib.xla_extension`` and spells liveness as
-  ``heartbeat_interval`` x ``max_missing_heartbeats``; there is no
-  ``recoverable`` flag, so the client's shutdown barrier is bounded
-  with a short ``shutdown_timeout`` instead (the teardown path already
-  tolerates a shutdown error — the service outlives every worker by
-  design).
-
-Both shapes want the same semantics: liveness detection effectively
-OFF (that job belongs to the socket control plane, whose watchdog can
-report-and-recover instead of aborting). This module hides the module
-probe and the kwarg translation so the call sites stay version-blind.
+Liveness detection is effectively OFF: that job belongs to the socket
+control plane, whose watchdog can report-and-recover instead of
+aborting. This module keeps the private import and the kwargs in one
+place, and fails at setup when a jaxlib lacks them.
 """
 
 from __future__ import annotations
 
-# effectively-never heartbeat budget (seconds / missed-beat count):
-# jaxlib's own watchdogs must never fire before the control plane's
+# effectively-never heartbeat budget (seconds): jaxlib's own watchdogs
+# must never fire before the control plane's
 _NEVER_S = 1 << 20
-_NEVER_BEATS = 1 << 10
 
 
 def distributed_runtime_module():
     """The module holding ``get_distributed_runtime_service`` /
-    ``_client``, wherever this jax hides it. Raises RuntimeError with a
-    pinning hint when neither spelling exists — fail at setup, not
-    mid-recovery (VERDICT r2 weak #7)."""
-    try:
-        from jax._src.lib import _jax as mod  # jax >= 0.9
-    except ImportError:
-        try:
-            from jax._src.lib import xla_extension as mod  # jax 0.4.x
-        except ImportError as e:
-            raise RuntimeError(
-                "rabit_tpu device-world coordination requires jaxlib's "
-                "private distributed runtime (jax._src.lib._jax or "
-                "jax._src.lib.xla_extension) — verified against jax "
-                "0.4.x and 0.9.x; pin jax or run without "
-                "rabit_dataplane=xla") from e
+    ``_client``. Raises RuntimeError with a pinning hint when a name is
+    missing — fail at setup, not mid-recovery."""
+    from jax._src.lib import _jax as mod
     for name in ("get_distributed_runtime_service",
                  "get_distributed_runtime_client"):
         if not hasattr(mod, name):
@@ -58,7 +34,7 @@ def distributed_runtime_module():
                 f"jaxlib private API {name!r} is missing in jaxlib "
                 f"{getattr(jaxlib, '__version__', '?')} — the device "
                 "plane's coordination contract is verified against "
-                "jaxlib 0.4.x and 0.9.x; pin jaxlib or run without "
+                "jaxlib 0.9.0; pin jaxlib or run without "
                 "rabit_dataplane=xla")
     return mod
 
@@ -67,37 +43,21 @@ def start_service(addr: str, num_nodes: int):
     """Start a coordination service with liveness detection disabled
     and a short shutdown grace (failure detection is the socket control
     plane's job, not the service's)."""
-    fn = distributed_runtime_module().get_distributed_runtime_service
-    try:
-        return fn(addr, num_nodes, heartbeat_timeout=_NEVER_S,
-                  shutdown_timeout=1)
-    except TypeError:  # jaxlib 0.4.x kwarg spelling
-        return fn(addr, num_nodes, heartbeat_interval=_NEVER_S,
-                  max_missing_heartbeats=_NEVER_BEATS, shutdown_timeout=1)
+    return distributed_runtime_module().get_distributed_runtime_service(
+        addr, num_nodes, heartbeat_timeout=_NEVER_S, shutdown_timeout=1)
 
 
 def connect_client(addr: str, rank: int, init_timeout: int):
     """Build and connect a coordination client with the same
-    never-abort posture. ``recoverable=True`` (where it exists) marks
-    the task recoverable so the service does not propagate this task's
-    disconnect as a fatal error to peers still polling; on 0.4.x, which
-    lacks the flag, a 1s ``shutdown_timeout`` bounds the teardown
-    barrier that recoverable would have skipped."""
-    fn = distributed_runtime_module().get_distributed_runtime_client
-    try:
-        client = fn(addr, rank,
-                    init_timeout=init_timeout,
-                    heartbeat_timeout=_NEVER_S,
-                    shutdown_on_destruction=False,
-                    use_compression=True,
-                    recoverable=True)
-    except TypeError:  # jaxlib 0.4.x kwarg spelling
-        client = fn(addr, rank,
-                    init_timeout=init_timeout,
-                    shutdown_timeout=1,
-                    heartbeat_interval=_NEVER_S,
-                    max_missing_heartbeats=_NEVER_BEATS,
-                    shutdown_on_destruction=False,
-                    use_compression=True)
+    never-abort posture. ``recoverable=True`` marks the task recoverable
+    so the service does not propagate this task's disconnect as a fatal
+    error to peers still polling."""
+    client = distributed_runtime_module().get_distributed_runtime_client(
+        addr, rank,
+        init_timeout=init_timeout,
+        heartbeat_timeout=_NEVER_S,
+        shutdown_on_destruction=False,
+        use_compression=True,
+        recoverable=True)
     client.connect()
     return client

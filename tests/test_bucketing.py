@@ -34,7 +34,7 @@ _COLLECTIVES = ("ppermute", "psum", "pmax", "pmin", "all_gather",
 def _count_eqns(jaxpr, names) -> int:
     """Primitive occurrences in a jaxpr, recursing into sub-jaxprs
     (pjit / shard_map / custom_vjp / scan all nest theirs in params)."""
-    from jax.core import Jaxpr, ClosedJaxpr
+    from jax.extend.core import Jaxpr, ClosedJaxpr
     n = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name in names:

@@ -21,6 +21,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 VALID_TABLE = {
     "schema": dispatch.SCHEMA,
+    "backend": "cpu",
     "table": {
         "float_sum": [
             {"max_n": 10000, "method": "tree", "wire": None},
@@ -83,6 +84,9 @@ def test_load_table_env_disable(monkeypatch, table_file):
     lambda d: d["table"]["float_sum"][0].__setitem__("wire", "fp4"),
     # "flat" (hier degradation target) must itself be a flat method
     lambda d: d["table"]["float_sum"][0].__setitem__("flat", "hier"),
+    # a table timed on another backend must not steer this one
+    lambda d: d.__setitem__("backend", "tpu"),
+    lambda d: d.pop("backend"),
 ])
 def test_load_table_rejects_malformed(tmp_path, monkeypatch, mutate):
     bad = json.loads(json.dumps(VALID_TABLE))
@@ -205,6 +209,7 @@ def test_resolve_hier_table_row_consults_grouping(tmp_path, monkeypatch):
     monkeypatch.delenv("RABIT_DATAPLANE_WIRE", raising=False)
     table = {
         "schema": dispatch.SCHEMA,
+    "backend": "cpu",
         "table": {
             "float_sum": [
                 {"max_n": 10000, "method": "tree", "wire": None},

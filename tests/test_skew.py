@@ -757,7 +757,7 @@ needs_8dev = pytest.mark.skipif(NDEV < 8, reason="needs 8 virtual devices")
 
 
 def _prims(jaxpr):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     out = []
     for eqn in jaxpr.eqns:
         out.append(eqn.primitive.name)

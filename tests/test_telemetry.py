@@ -257,6 +257,7 @@ def test_export_at_shutdown(tmp_path, monkeypatch, telem):
 
 VALID_TABLE = {
     "schema": dispatch.SCHEMA,
+    "backend": "cpu",
     "table": {
         "float_sum": [
             {"max_n": 10000, "method": "tree", "wire": None},
@@ -356,7 +357,7 @@ def test_device_allreduce_silent_when_disabled(no_table):
 
 def _prims(jaxpr):
     """Ordered primitive names, recursing into sub-jaxprs."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     out = []
     for eqn in jaxpr.eqns:
         out.append(eqn.primitive.name)

@@ -1,0 +1,89 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+
+No chip is needed: the TPU compiler is installed, and it compiles for a
+topology that is described, not attached. Nothing runs, so these tests
+say nothing about results or speed; they catch what interpret mode
+cannot (tiling, VMEM budgets, Mosaic lowering) before chip time is
+spent. The topology is described inside a fixture, never at import:
+only one process at a time may load the TPU library, and the driver
+runs the suite with several workers. Keep every such test in this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from rabit_tpu.ops import pallas_kernels as PK
+
+ROWS = 1 << 21   # bench.py's rows per worker
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def compiled_kernels(monkeypatch):
+    """Compiled Mosaic, not interpret mode; and no persistent cache: a
+    compile for a described chip is written to it but cannot be read
+    back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.delenv("RABIT_PALLAS_INTERPRET", raising=False)
+    monkeypatch.delenv("RABIT_FLASH_BWD", raising=False)
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.mark.parametrize("precision", ["high", "fast"])
+@pytest.mark.parametrize("nbins", [1024, 28 * 256])
+def test_histogram_kernel_compiles(one_chip, nbins, precision):
+    """bench.py's cell (1024 bins) and the HIGGS gpu_hist shape (28
+    features x 256 bins), at 2,097,152 rows."""
+    row = lambda dt: jax.ShapeDtypeStruct((ROWS,), dt,  # noqa: E731
+                                          sharding=one_chip)
+    compiled = PK._histogram_tpu_impl.lower(
+        row(jnp.int32), row(jnp.float32), row(jnp.float32), nbins=nbins,
+        precision=precision, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("t", [512, 1024])
+def test_flash_block_compiles(one_chip, t, backward):
+    """flash_block at H=8, T=S=t, D=128, causal; with ``backward`` the
+    fused Pallas backward as well. At T=S=2048 the fused backward runs
+    out of VMEM (see ``_flash_compiler_params``)."""
+    h, d = 8, 128
+    blk = jax.ShapeDtypeStruct((h, t, d), jnp.bfloat16, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((h, t), jnp.float32, sharding=one_chip)
+    acc = jax.ShapeDtypeStruct((h, t, d), jnp.float32, sharding=one_chip)
+
+    def fwd(q, k, v, m, l, o):
+        pos = jnp.arange(t)
+        return PK.flash_block(q, k, v, m, l, o, pos[None, :] > pos[:, None],
+                              1.0 / np.sqrt(d))
+
+    fn = (jax.grad(lambda *a: sum(x.sum() for x in fwd(*a)),
+                   argnums=(0, 1, 2)) if backward else fwd)
+    compiled = jax.jit(fn).lower(blk, blk, blk, row, row, acc).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -284,9 +284,7 @@ def test_coordinator_services_reaped_across_epochs():
 def test_private_api_guard_dataplane(monkeypatch):
     """A jax upgrade that removes the private client API must fail at
     data-plane construction with a pinned, actionable error (VERDICT r2
-    weak #7) — not mid-recovery. Goes through the jaxcompat probe so
-    the test holds on every jax this repo supports (0.4.x and 0.9.x
-    stash the bindings in different modules)."""
+    weak #7) — not mid-recovery."""
     from rabit_tpu.engine.dataplane import XlaDataPlane
     from rabit_tpu.utils import jaxcompat
     mod = jaxcompat.distributed_runtime_module()
@@ -344,3 +342,20 @@ def test_topo_command_serves_discovered_grouping():
         b.close()
     finally:
         tr.stop()
+
+
+@pytest.mark.parametrize("platforms", ["", "tpu", "cpu,tpu"])
+def test_launch_refuses_xla_workers_sharing_a_chip(monkeypatch, platforms):
+    """xla-data-plane workers not held off the TPU would each claim
+    every chip of the host: the launcher refuses before spawning."""
+    from rabit_tpu.tracker import launch as L
+    monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    cmd = [sys.executable, "-c", "pass", "rabit_dataplane=xla"]
+    with pytest.raises(RuntimeError, match="share the host's chips"):
+        L.launch(2, cmd)
+    with pytest.raises(RuntimeError, match="share the host's chips"):
+        L.submit_launch("127.0.0.1:1", "job", 2, cmd)
+    # one worker, or workers pinned to the CPU backend, are fine
+    L._refuse_shared_chip(1, True)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    L._refuse_shared_chip(2, True)

@@ -261,7 +261,7 @@ def test_wire_itemsize_prices_phase_split():
 
 
 def test_dispatch_accepts_v3_spec_columns(tmp_path):
-    doc = {"schema": "rabit_tpu.collective_sweep/v3",
+    doc = {"schema": "rabit_tpu.collective_sweep/v3", "backend": "cpu",
            "table": {"float_sum": [
                {"max_n": 1000, "method": "tree", "wire": None},
                {"max_n": None, "method": "ring",
