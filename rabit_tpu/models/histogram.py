@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
+from .. import telemetry
 from ..ops.reducers import SUM
 from ..parallel.collectives import (
     shard_map, unchecked_shard_map, tree_allreduce, ring_allreduce,
@@ -94,9 +95,6 @@ def local_histogram(grad: jax.Array, hess: jax.Array, bins: jax.Array,
     raise ValueError(f"unknown method {method!r}")
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("nbins", "mesh", "axis", "method",
-                                    "precision"))
 def distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
                           axis: str = "workers", method: str = "auto",
                           precision: str = "high") -> jax.Array:
@@ -106,7 +104,22 @@ def distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
     grad/hess [p, n_local], bins [p, n_local]. Output [nbins, 2]
     replicated — the allreduced histogram every worker needs to find the
     best split.
+
+    The host call is the phase ``rabit.hist.enqueue``
+    (``telemetry.phase``); ``distributed_histogram.lower`` lowers the
+    jitted program.
     """
+    with telemetry.phase("rabit.hist.enqueue"):
+        return _distributed_histogram(grad, hess, bins, nbins, mesh, axis,
+                                      method, precision)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("nbins", "mesh", "axis", "method",
+                                    "precision"))
+def _distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
+                           axis: str = "workers", method: str = "auto",
+                           precision: str = "high") -> jax.Array:
     use_ring = nbins * 2 >= RING_MINCOUNT_DEFAULT
 
     def per_shard(g, h, b):
@@ -126,6 +139,9 @@ def distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
     return sm(per_shard, mesh=mesh,
               in_specs=(P(axis), P(axis), P(axis)),
               out_specs=P())(grad, hess, bins)
+
+
+distributed_histogram.lower = _distributed_histogram.lower
 
 
 def host_histogram(grad: np.ndarray, hess: np.ndarray, bins: np.ndarray,

@@ -46,6 +46,9 @@ import numpy as np
 
 _CHUNK = 16384   # rows per grid step
 _ATILE = 512    # high-level bin groups per grid step (VMEM bound)
+# the build kernel's name on a device trace (the HLO custom call takes
+# the pallas_call's name): part of the interface, read by the benchmark
+HIST_KERNEL_NAME = "rabit_hist_build"
 
 
 def _out_struct(shape, dtype, *arrs):
@@ -156,6 +159,7 @@ def _histogram_tpu_impl(bins, grad, hess, nbins, precision, interpret):
                               bins, grad, hess),
         compiler_params=_hist_compiler_params(),
         interpret=interpret,
+        name=HIST_KERNEL_NAME,
     )(bins, *comps)
     # out[k, a, c] -> [r, a_pad*C] -> slice bins -> [nbins, 2]
     comps = out.reshape(r, -1)[:, :nbins]

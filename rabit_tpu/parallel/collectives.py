@@ -1000,10 +1000,51 @@ def device_allreduce(xs: jax.Array, mesh: Mesh, op: int = SUM,
     the table says hierarchy wins at this size AND the grouping is
     genuinely two-level; ``method="hier"`` on a degenerate world runs
     the matching flat schedule.
+
+    The host call is the phase ``rabit.allreduce`` (``telemetry.phase``),
+    with the children ``rabit.allreduce.resolve`` (skew, topology,
+    dispatch) and ``rabit.allreduce.enqueue`` (the jitted program's
+    call); what is left is its bookkeeping.
     """
     if axis is None:
         axis = mesh.axis_names[0]
     n = int(np.prod(xs.shape[1:]))
+    with telemetry.phase("rabit.allreduce",
+                         nbytes=n * xs.dtype.itemsize) as top:
+        with telemetry.phase("rabit.allreduce.resolve", parent=top):
+            method, wire, groups, adapted = _allreduce_plan(
+                xs, mesh, axis, n, op, method, wire, groups)
+        cost = _profile.record_cost(
+            "allreduce", method, wire, n, xs.dtype.itemsize,
+            mesh.shape[axis], group_size=len(groups[0]) if groups else None)
+        extra = ({"cost_flops": cost["flops"],
+                  "cost_wire_bytes": cost["wire_bytes"],
+                  "cost_hops": cost["hops"]} if cost else {})
+        if method == "hier" and groups:
+            extra["hosts"] = len(groups)
+        if adapted:
+            extra["adapted"] = adapted
+        sp = telemetry.span("allreduce", nbytes=n * xs.dtype.itemsize,
+                            op=OP_NAMES.get(op, str(op)), method=method,
+                            wire=wire, **extra)
+        with sp:
+            t0 = time.perf_counter()
+            with telemetry.phase("rabit.allreduce.enqueue", parent=top), \
+                    _profile.jit_probe("allreduce", _allreduce_global):
+                out = _allreduce_global(xs, mesh, axis, op, method, wire,
+                                        groups)
+            if sp.live:
+                # only when measuring: a span closed on dispatch would
+                # time the async enqueue, not the collective
+                out.block_until_ready()
+                _stamp_exposed(sp, t0)
+    return out
+
+
+def _allreduce_plan(xs, mesh: Mesh, axis: str, n: int, op: int,
+                    method: str, wire, groups):
+    """``device_allreduce``'s schedule, ``(method, wire, groups,
+    adapted)``: skew sync, topology, dispatch and the skew plan."""
     if _skew.adapt_enabled():
         # BEFORE resolve: dispatch's method-family election reads the
         # agreed digest too, so it must be current at this boundary
@@ -1032,30 +1073,7 @@ def device_allreduce(xs: jax.Array, mesh: Mesh, op: int = SUM,
                 wire = None  # raw ppermute payloads, codec never applies
             adapted = f"{plan['kind']}@{plan['laggard']}"
         _skew.note_applied(adapted)
-    cost = _profile.record_cost(
-        "allreduce", method, wire, n, xs.dtype.itemsize, mesh.shape[axis],
-        group_size=len(groups[0]) if groups else None)
-    extra = ({"cost_flops": cost["flops"],
-              "cost_wire_bytes": cost["wire_bytes"],
-              "cost_hops": cost["hops"]} if cost else {})
-    if method == "hier" and groups:
-        extra["hosts"] = len(groups)
-    if adapted:
-        extra["adapted"] = adapted
-    sp = telemetry.span("allreduce", nbytes=n * xs.dtype.itemsize,
-                        op=OP_NAMES.get(op, str(op)), method=method,
-                        wire=wire, **extra)
-    with sp:
-        t0 = time.perf_counter()
-        with _profile.jit_probe("allreduce", _allreduce_global):
-            out = _allreduce_global(xs, mesh, axis, op, method, wire,
-                                    groups)
-        if sp.live:
-            # only when measuring: a span closed on dispatch would time
-            # the async enqueue, not the collective
-            out.block_until_ready()
-            _stamp_exposed(sp, t0)
-    return out
+    return method, wire, groups, adapted
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "axis", "op", "wire",

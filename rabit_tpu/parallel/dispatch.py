@@ -346,11 +346,13 @@ def resolve(n: int, dtype, op: int, axis_size: int,
     """
     import jax.numpy as jnp
 
+    from .. import telemetry
     from ..ops.reducers import BITOR, SUM, OP_NAMES
     from ..telemetry import skew
     from . import topology
     requested = method
-    table = load_table()
+    with telemetry.phase("rabit.dispatch.load_table"):
+        table = load_table()
     wire_eligible = op == SUM and jnp.issubdtype(jnp.dtype(dtype),
                                                  jnp.floating)
     hier_ok = (topology.hier_enabled()
@@ -427,7 +429,6 @@ def resolve(n: int, dtype, op: int, axis_size: int,
         # explicit per-call spec: canonicalize (folds the env block into
         # specs that don't pin one) so it is a stable jit cache key
         wire = _wirespec.canonical_wire(wire)
-    from .. import telemetry
     provenance = ("explicit" if requested != "auto"
                   else "skew_adapted" if adapted
                   else "table" if table is not None else "fallback")

@@ -5,6 +5,8 @@ The module-level API fronts one process-wide :class:`Recorder`:
     from rabit_tpu import telemetry
     with telemetry.span("allreduce", nbytes=nb, method="ring"):
         ...                      # timed only when rabit_telemetry=1
+    with telemetry.phase("rabit.allreduce.resolve"):
+        ...                      # also recorded inside a profiler session
 
 Off by default (``rabit_telemetry=0``). When disabled, ``span()``
 returns a shared no-op context (``live == False``) and
@@ -19,10 +21,12 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import time
 from typing import Optional
 
 from .recorder import (Recorder, NULL_SPAN,  # noqa: F401  (re-export)
                        DEFAULT_CAPACITY, size_bucket)
+from .recorder import _Phase
 from .export import (build_summary, export_summary,  # noqa: F401
                      build_chrome_trace, export_chrome_trace,
                      SUMMARY_KIND, TRACE_KIND)
@@ -69,6 +73,30 @@ def span(name: str, nbytes: int = 0, op=None, method=None, wire=None,
     """Timed context for one operation — the tentpole entry point."""
     return _REC.span(name, nbytes=nbytes, op=op, method=method, wire=wire,
                      **_stamp_round(attrs))
+
+
+def _profiling() -> bool:
+    """Whether a ``jax.profiler`` session is recording. The first call
+    imports jax and rebinds this name to JAX's own check, so later calls
+    cost one C++ call."""
+    global _profiling
+    from jax.profiler import TraceAnnotation
+    _profiling = TraceAnnotation.is_enabled
+    return _profiling()
+
+
+def phase(name: str, nbytes: int = 0, parent=None):
+    """One host phase of a call (``rabit.allreduce.resolve``, ...), live
+    while telemetry is on or a profiler session records, so that a
+    traced window records it with no knob. Live, it enters a
+    ``jax.profiler.TraceAnnotation(name)``, records its ``perf_counter``
+    start, duration, bytes and parent (``parent``, else the thread's
+    innermost open phase) into the recorder's phase store and counter
+    rows, and never waits for the device. Off, it returns the shared
+    no-op span. A profiler session does not make :func:`span` live."""
+    if not _REC.enabled and not _profiling():
+        return NULL_SPAN
+    return _Phase(_REC, name, nbytes, parent, time.perf_counter())
 
 
 def record_span(name: str, dur_s: float, nbytes: int = 0, **kw) -> None:

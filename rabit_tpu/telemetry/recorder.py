@@ -19,10 +19,17 @@ Design constraints (ISSUE 2 tentpole):
 - **thread-safe**: the XLA data-plane callback fires on C++ threads;
   all mutation happens under one lock (the enabled check stays
   lock-free — a torn read there only means one span more or less).
+- **host phases** (``telemetry.phase``): nested spans of the host
+  work inside one call, each with an id, its parent's id and an
+  absolute ``perf_counter`` start, kept in a store of their own
+  (``PHASE_CAPACITY``, so a few seconds of calls fit whole) and folded
+  into the same counter rows. They never wait for the device.
 """
 
 from __future__ import annotations
 
+import collections
+import itertools
 import os
 import threading
 import time
@@ -32,6 +39,11 @@ _ENV_ENABLED = "RABIT_TELEMETRY"
 _ENV_BUFFER = "RABIT_TELEMETRY_BUFFER"
 
 DEFAULT_CAPACITY = 4096
+# host phases kept: a profiled window of seconds at a few phases per
+# call, thousands of calls a second
+PHASE_CAPACITY = 1 << 16
+# one host phase in a snapshot; ``start`` is time.perf_counter() seconds
+_PHASE_FIELDS = ("id", "parent", "name", "start", "dur", "bytes")
 
 # Size buckets: powers of 4 from 1 KiB to 256 MiB (the payload range the
 # dispatch table spans), plus an open top bucket and "0B" for
@@ -102,6 +114,47 @@ class _Span:
         return False
 
 
+class _Phase:
+    """One live host phase: a ``jax.profiler.TraceAnnotation`` of the
+    same name (so a profiler session shows it beside the device ops)
+    inside a ``perf_counter`` interval from ``t0`` to the store.
+    The interval holds the phase's own cost, so a parent's self time
+    holds its children's. Few calls: a profiler session traces each."""
+
+    live = True
+    __slots__ = ("_rec", "name", "nbytes", "parent", "id", "_stack",
+                 "_ann", "_t0")
+
+    def __init__(self, rec, name, nbytes, parent, t0):
+        self._t0 = t0
+        self._rec = rec
+        self.name = name
+        self.nbytes = nbytes
+        self.parent = parent
+
+    def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        local = self._rec._local
+        try:
+            stack = local.stack
+        except AttributeError:
+            stack = local.stack = []
+        if self.parent is None:
+            self.parent = stack[-1] if stack else NULL_SPAN
+        self.id = next(self._rec._phase_ids)
+        stack.append(self)
+        self._stack = stack
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self._stack.pop()
+        self._rec._record_phase(self)
+        return False
+
+
 class Recorder:
     """Ring-buffered span store + exact counters. One module-level
     instance serves the process; tests may build their own."""
@@ -109,6 +162,8 @@ class Recorder:
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
                  enabled: Optional[bool] = None):
         self._lock = threading.Lock()
+        self._local = threading.local()    # open phases of each thread
+        self._phase_ids = itertools.count(1)
         self.reset(capacity=capacity, enabled=enabled)
 
     # -- lifecycle --------------------------------------------------------
@@ -128,6 +183,10 @@ class Recorder:
             self.dropped = 0        # spans overwritten in the ring
             self._counters: dict = {}
             self._rounds: dict = {}
+            self._phases = collections.deque(  # guarded-by: _lock
+                maxlen=PHASE_CAPACITY)
+            self.phases_dropped = 0  # guarded-by: _lock
+            self._unfolded = 0  # guarded-by: _lock
             self.t_base = time.perf_counter()
             # wall-clock anchor for the same instant as t_base: spans'
             # relative t0 + t_base_unix gives an absolute arrival time
@@ -143,6 +202,31 @@ class Recorder:
         if not self.enabled:
             return NULL_SPAN
         return _Span(self, name, int(nbytes), op, method, wire, attrs)
+
+    def _record_phase(self, ph: _Phase) -> None:
+        """Store one finished phase (``_PHASE_FIELDS``); its duration
+        ends here. Its counter row is bumped later, by
+        :meth:`_fold_phases_locked`, before anything reads the counters
+        or the store drops it."""
+        parent = ph.parent.id if ph.parent.live else 0
+        with self._lock:
+            n = len(self._phases)
+            if n == PHASE_CAPACITY:
+                if self._unfolded == n:
+                    self._fold_phases_locked()
+                self.phases_dropped += 1
+            self._phases.append((ph.id, parent, ph.name, ph._t0,
+                                 time.perf_counter() - ph._t0, ph.nbytes))
+            self._unfolded += 1
+
+    def _fold_phases_locked(self) -> None:
+        """Fold the phases stored since the last fold (the newest
+        ``_unfolded``) into the exact counter rows."""
+        for _, _, name, _, dur, nbytes in itertools.islice(
+                reversed(self._phases), self._unfolded):
+            self._bump_locked((name, "", "", "", size_bucket(nbytes), ""),
+                              nbytes, dur)
+        self._unfolded = 0
 
     def record_span(self, name: str, dur_s: float, nbytes: int = 0,
                     op=None, method=None, wire=None, provenance: str = "",
@@ -231,6 +315,7 @@ class Recorder:
         election calls this per resolve)."""
         out = []
         with self._lock:
+            self._fold_phases_locked()
             for (nm, op, method, wire, bucket, prov), c in \
                     self._counters.items():
                 if nm != name:
@@ -248,6 +333,7 @@ class Recorder:
         """Point-in-time copy: spans in chronological order, counter
         rows as dicts (keys flattened into fields)."""
         with self._lock:
+            self._fold_phases_locked()
             if len(self._spans) < self.capacity:
                 spans = list(self._spans)
             else:
@@ -264,10 +350,14 @@ class Recorder:
                 if prov:
                     row["provenance"] = prov
                 counters.append(row)
+            phases = [dict(zip(_PHASE_FIELDS, p)) for p in self._phases]
             return {"enabled": self.enabled,
                     "capacity": self.capacity,
                     "recorded": self.recorded,
                     "dropped": self.dropped,
                     "t_base_unix": self.t_base_unix,
                     "spans": [dict(s) for s in spans],
-                    "counters": counters}
+                    "counters": counters,
+                    "phases": phases,
+                    "phases_dropped": self.phases_dropped}
+

@@ -10,11 +10,13 @@ runs the suite with several workers. Keep every such test in this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from rabit_tpu.ops import pallas_kernels as PK
@@ -64,6 +66,24 @@ def test_histogram_kernel_compiles(one_chip, nbins, precision):
         row(jnp.int32), row(jnp.float32), row(jnp.float32), nbins=nbins,
         precision=precision, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_histogram_kernel_keeps_its_name(topo):
+    """distributed_histogram.lower (the jitted program under the public
+    wrapper) at an unaligned row count: the build kernel's instruction
+    carries the stable name a device trace shows, not a function's."""
+    from rabit_tpu.models import histogram as H
+    mesh = Mesh(topo.devices[:1], ("workers",))
+    sh = NamedSharding(mesh, P("workers"))
+    row = lambda dt: jax.ShapeDtypeStruct((1, 3 * PK._CHUNK + 5), dt,  # noqa: E731
+                                          sharding=sh)
+    text = H.distributed_histogram.lower(
+        row(jnp.float32), row(jnp.float32), row(jnp.int32), nbins=28 * 256,
+        mesh=mesh, method="pallas").compile().as_text()
+    kernels = re.findall(r"%([\w.]+) = .* custom-call\(.*"
+                         r'custom_call_target="tpu_custom_call"', text)
+    assert [re.sub(r"\.\d+$", "", k) for k in kernels] == \
+        [PK.HIST_KERNEL_NAME] == ["rabit_hist_build"]
 
 
 @pytest.mark.parametrize("backward", [False, True],
