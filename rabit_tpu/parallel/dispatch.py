@@ -199,60 +199,35 @@ def _valid_rows(rows) -> bool:
     return rows[-1].get("max_n") is None  # must cover every size
 
 
-# path -> (mtime, table-or-None, source); a changed file re-parses, a
-# bad file is remembered as bad until it changes
+# What load_table() remembers, as (stamp, table-or-None, source):
+# under key None the discovered table, stamped with the value of
+# RABIT_DISPATCH_TABLE it was found under, so the steady path makes no
+# filesystem call; under an explicit path that file's parse, stamped
+# with its mtime, so a changed file re-parses. A bad table is
+# remembered as bad until its stamp changes or the cache is cleared.
 _cache: dict = {}
 _source = "documented constants (no table loaded yet)"
 
 
 def clear_cache() -> None:
+    """Forget every remembered table: the next :func:`load_table`
+    discovers again (a newly dropped ``COLLECTIVE_SWEEP_*.json`` takes
+    effect from then on)."""
     _cache.clear()
 
 
 def epoch_reset(world: int) -> None:
-    """Elastic-membership epoch hook (lint rule R002): the parsed
-    dispatch table is cached per path, and its rows steer method
-    choice per axis size — after a resize the relevant axis size
-    changed, so a stale parse must not outlive the world that loaded
-    it (re-parsing one JSON file per epoch transition is free)."""
+    """Elastic-membership epoch hook (lint rule R002): the discovered
+    table is found once per epoch, and its rows steer method choice per
+    axis size — after a resize the relevant axis size changed, so a
+    stale discovery must not outlive the world that made it (one
+    directory scan and one JSON parse per epoch transition is free)."""
     del world  # resolve() receives the new axis size per call
     clear_cache()
 
 
-def load_table(path: Optional[str] = None) -> Optional[dict]:
-    """The committed dispatch table, or None (→ fallback constants).
-
-    Resolution order: explicit ``path`` arg, ``RABIT_DISPATCH_TABLE``
-    env (``none``/``off``/``0`` disables), newest
-    ``COLLECTIVE_SWEEP_*.json`` under ``benchmarks/artifacts/`` (repo
-    root also scanned for compatibility). A missing file, a schema
-    outside ``ACCEPTED_SCHEMAS`` (v2 and the legacy v1 — future majors
-    must not be misread), malformed rows, or a table whose recorded
-    ``backend`` is not the running one all yield None — dispatch then
-    uses the documented defaults, and :func:`table_source` says why.
-    """
-    global _source
-    if path is None:
-        env = os.environ.get(_TABLE_ENV)
-        if env in ("none", "off", "0"):
-            _source = f"documented constants ({_TABLE_ENV}={env})"
-            return None
-        path = env or _newest_sweep()
-    if not path:
-        _source = "documented constants (no table found)"
-        return None
-    try:
-        mtime = os.path.getmtime(path)
-    except OSError:
-        _source = f"documented constants ({path} missing)"
-        return None
-    from ..telemetry import profile
-    hit = _cache.get(path)
-    if hit is not None and hit[0] == mtime:
-        profile.cache_event("dispatch_table", hit=True)
-        _source = hit[2]
-        return hit[1]
-    profile.cache_event("dispatch_table", hit=False)
+def _parse(path: str) -> Tuple[Optional[dict], str]:
+    """``(table-or-None, source)`` of one sweep file."""
     table = None
     try:
         with open(path) as f:
@@ -263,23 +238,73 @@ def load_table(path: Optional[str] = None) -> Optional[dict]:
                     and _valid_rows(cand.get("float_sum"))
                     and _valid_rows(cand.get("other"))):
                 table = cand
+    except FileNotFoundError:
+        return None, f"documented constants ({path} missing)"
     except (OSError, ValueError):
-        table = None
+        pass
     if table is None:
-        _source = f"documented constants ({path} unreadable or invalid)"
+        return None, f"documented constants ({path} unreadable or invalid)"
+    import jax
+    running, measured = jax.default_backend(), data.get("backend")
+    if measured != running:
+        # a table timed on another fabric (every committed sweep ran on
+        # the 8-device CPU mesh) must not steer this one
+        return None, (f"documented constants ({path} was measured on "
+                      f"backend {measured!r}, running {running!r})")
+    return table, f"table {path} (backend {running!r})"
+
+
+def _discover(env: Optional[str]) -> Tuple[Optional[dict], str]:
+    """``(table-or-None, source)`` for ``RABIT_DISPATCH_TABLE`` = ``env``:
+    disabled, the file it names, or the newest committed sweep."""
+    if env in ("none", "off", "0"):
+        return None, f"documented constants ({_TABLE_ENV}={env})"
+    path = env or _newest_sweep()
+    if not path:
+        return None, "documented constants (no table found)"
+    return _parse(path)
+
+
+def load_table(path: Optional[str] = None) -> Optional[dict]:
+    """The committed dispatch table, or None (→ fallback constants).
+
+    Resolution order: explicit ``path`` arg, ``RABIT_DISPATCH_TABLE``
+    env (``none``/``off``/``0`` disables), newest
+    ``COLLECTIVE_SWEEP_*.json`` under ``benchmarks/artifacts/`` (repo
+    root also scanned for compatibility). A missing file, a schema
+    outside ``ACCEPTED_SCHEMAS`` (v3, v2 and the legacy v1 — future
+    majors must not be misread), malformed rows, or a table whose
+    recorded ``backend`` is not the running one all yield None —
+    dispatch then uses the documented defaults, and
+    :func:`table_source` says why.
+
+    Without ``path`` the table is discovered once and remembered: the
+    first call in the process, the first after :func:`clear_cache` or
+    :func:`epoch_reset`, and the first after ``RABIT_DISPATCH_TABLE``
+    changes value scan and parse; every other call is one environment
+    read. An explicit ``path`` is re-parsed when its mtime changes.
+    The ``dispatch_table`` cache counter's misses count discoveries
+    and parses, its hits the calls that made neither.
+    """
+    global _source
+    from ..telemetry import profile
+    if path is None:
+        stamp = os.environ.get(_TABLE_ENV)
     else:
-        import jax
-        running, measured = jax.default_backend(), data.get("backend")
-        if measured != running:
-            # a table timed on another fabric (every committed sweep
-            # ran on the 8-device CPU mesh) must not steer this one
-            table = None
-            _source = (f"documented constants ({path} was measured on "
-                       f"backend {measured!r}, running {running!r})")
-        else:
-            _source = f"table {path} (backend {running!r})"
+        try:
+            stamp = os.path.getmtime(path)
+        except OSError:
+            _source = f"documented constants ({path} missing)"
+            return None
+    hit = _cache.get(path)
+    if hit is not None and hit[0] == stamp:
+        profile.cache_event("dispatch_table", hit=True)
+        _source = hit[2]
+        return hit[1]
+    profile.cache_event("dispatch_table", hit=False)
+    table, _source = _discover(stamp) if path is None else _parse(path)
     log.log_info("dispatch: %s", _source)
-    _cache[path] = (mtime, table, _source)
+    _cache[path] = (stamp, table, _source)
     return table
 
 

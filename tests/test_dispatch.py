@@ -140,6 +140,149 @@ def test_committed_artifact_loads():
         dispatch.clear_cache()
 
 
+# ------------------------------------------------ remembered discovery
+
+
+@pytest.fixture
+def prof():
+    from rabit_tpu.telemetry import profile
+    profile.reset(enabled=True)
+    yield profile
+    profile.reset(enabled=False)
+
+
+def _table_counts(profile):
+    rows = [r for r in profile.snapshot()["jit_cache"]
+            if r["fn"] == "dispatch_table"]
+    return (rows[0]["hits"], rows[0]["misses"]) if rows else (0, 0)
+
+
+@pytest.fixture
+def artifacts(tmp_path, monkeypatch):
+    """An empty artifacts directory and repo root for discovery, with
+    no table named in the environment."""
+    arts, root = tmp_path / "artifacts", tmp_path / "root"
+    arts.mkdir()
+    root.mkdir()
+    monkeypatch.setattr(dispatch, "artifacts_dir", lambda: str(arts))
+    monkeypatch.setattr(dispatch, "_repo_root", lambda: str(root))
+    monkeypatch.delenv("RABIT_DISPATCH_TABLE", raising=False)
+    monkeypatch.delenv("RABIT_DATAPLANE_WIRE", raising=False)
+    monkeypatch.delenv("RABIT_DATAPLANE_WIRE_MINCOUNT", raising=False)
+    dispatch.clear_cache()
+    yield arts
+    dispatch.clear_cache()
+
+
+@pytest.fixture
+def fs_calls(monkeypatch):
+    """Counts every glob, stat, listing and open dispatch could make."""
+    import glob
+    calls = []
+
+    def counting(name, real):
+        def f(*a, **kw):
+            calls.append(name)
+            return real(*a, **kw)
+        return f
+
+    for mod, name in ((glob, "glob"), (glob, "iglob"),
+                      (os.path, "getmtime"), (os.path, "exists"),
+                      (os, "stat"), (os, "listdir"), (os, "scandir")):
+        monkeypatch.setattr(mod, name, counting(name, getattr(mod, name)))
+    monkeypatch.setattr(dispatch, "open", counting("open", open),
+                        raising=False)
+    return calls
+
+
+def test_remembered_table_makes_no_filesystem_call(artifacts, fs_calls,
+                                                   prof):
+    (artifacts / "COLLECTIVE_SWEEP_test.json").write_text(
+        json.dumps(VALID_TABLE))
+    f32 = np.dtype(np.float32)
+    assert dispatch.resolve(50000, f32, SUM, 8)[0] == "bidir"
+    assert {"glob", "open"} <= set(fs_calls)
+    fs_calls.clear()
+    for _ in range(100):
+        assert dispatch.resolve(50000, f32, SUM, 8)[0] == "bidir"
+    assert fs_calls == []
+    assert _table_counts(prof) == (100, 1)
+
+
+@pytest.mark.parametrize("reset", [dispatch.clear_cache,
+                                   lambda: dispatch.epoch_reset(4)],
+                         ids=["clear_cache", "epoch_reset"])
+def test_reset_discovers_a_dropped_table(artifacts, fs_calls, prof, reset):
+    f32 = np.dtype(np.float32)
+    assert dispatch.load_table() is None
+    assert dispatch.table_source() == "documented constants (no table found)"
+    assert dispatch.resolve(50000, f32, SUM, 8)[0] == "ring"  # fallback
+    (artifacts / "COLLECTIVE_SWEEP_test.json").write_text(
+        json.dumps(VALID_TABLE))
+    fs_calls.clear()
+    assert dispatch.load_table() is None  # not before the reset
+    assert dispatch.resolve(50000, f32, SUM, 8)[0] == "ring"
+    assert fs_calls == []
+    reset()
+    assert dispatch.load_table() == VALID_TABLE["table"]
+    assert dispatch.resolve(50000, f32, SUM, 8)[0] == "bidir"
+    assert "glob" in fs_calls
+    assert _table_counts(prof)[1] == 2  # one discovery before, one after
+
+
+def test_table_env_change_takes_effect_without_clear(table_file, prof,
+                                                     monkeypatch):
+    f32 = np.dtype(np.float32)
+    assert dispatch.resolve(50000, f32, SUM, 8)[0] == "bidir"
+    monkeypatch.setenv("RABIT_DISPATCH_TABLE", "none")
+    assert dispatch.resolve(50000, f32, SUM, 8)[0] == "ring"
+    assert dispatch.table_source() == (
+        "documented constants (RABIT_DISPATCH_TABLE=none)")
+    monkeypatch.setenv("RABIT_DISPATCH_TABLE", str(table_file))
+    assert dispatch.resolve(50000, f32, SUM, 8)[0] == "bidir"
+    assert dispatch.table_source().startswith(f"table {table_file}")
+    assert _table_counts(prof) == (0, 3)
+
+
+_BOUNDARIES = (1024, 10000, dispatch.RING_MINCOUNT_DEFAULT, 500000,
+               dispatch.WIRE_MINCOUNT_DEFAULT)
+
+
+@pytest.mark.parametrize("source", ["table", "fallback"])
+@pytest.mark.parametrize("dtype,op", [(np.float32, SUM), (np.uint32, BITOR)],
+                         ids=["sum_f32", "bitor_u32"])
+def test_remembered_table_resolves_as_fresh(source, dtype, op, tmp_path,
+                                            monkeypatch):
+    """The remembered table gives the same ``(method, wire)`` as one
+    loaded afresh, at and around every row boundary of VALID_TABLE, the
+    fallback crossover, the BitOR override and the wire gate."""
+    p = tmp_path / "COLLECTIVE_SWEEP_test.json"
+    p.write_text(json.dumps(VALID_TABLE))
+    monkeypatch.setenv("RABIT_DISPATCH_TABLE",
+                       str(p) if source == "table" else "none")
+    monkeypatch.setenv("RABIT_DATAPLANE_WIRE", "int8")
+    monkeypatch.delenv("RABIT_DATAPLANE_WIRE_MINCOUNT", raising=False)
+    dt = np.dtype(dtype)
+    sizes = sorted({b + d for b in _BOUNDARIES for d in (-1, 0, 1)})
+    dispatch.clear_cache()
+    try:
+        remembered = [dispatch.resolve(n, dt, op, 8) for n in sizes]
+        fresh = []
+        for n in sizes:
+            dispatch.clear_cache()
+            fresh.append(dispatch.resolve(n, dt, op, 8))
+    finally:
+        dispatch.clear_cache()
+    assert remembered == fresh
+    # both sources were really consulted: the table's rows differ from
+    # the fallback constants at 50k-500k float elements
+    methods = {m for m, _ in remembered}
+    if source == "table" and op == SUM:
+        assert {"tree", "bidir", "swing"} <= methods
+    else:
+        assert methods <= {"tree", "ring"}
+
+
 # ------------------------------------------------------- resolve: method
 
 
