@@ -139,6 +139,166 @@ def test_new_cell_is_files_and_entries_only(tmp_path):
     assert out["metrics"]["rounds_done"]["value"] == out["attempted"]
 
 
+# -- the dense layout, made ready for a wide build added later -----------------
+
+def _dense_as_entries(orig):
+    """Test-only stand-in for a program that takes the dense form: it
+    flattens [p, n, F] bins and [p, n] weights to the entries form and
+    calls the program; the entries form passes through."""
+    def f(grad, hess, bins, nbins, mesh, axis="workers", method="auto",
+          precision="high"):
+        import jax.numpy as jnp
+        if bins.ndim == grad.ndim + 1:
+            grad, hess = (jnp.broadcast_to(v[..., None], bins.shape)
+                          .reshape(v.shape[0], -1) for v in (grad, hess))
+            bins = bins.reshape(bins.shape[0], -1)
+        return orig(grad, hess, bins, nbins, mesh, axis, method,
+                    precision=precision)
+    return f
+
+
+def _add_dense_cell(root) -> None:
+    """A checkout's benchmark under ``root`` with one more cell, made as a
+    later PR makes it, of new files and entries only: an epsilon-shaped
+    configuration in the dense layout, the level mix, a limits file, and
+    the cell's name in the lists of rows_per_s and the histogram
+    per-layer metrics."""
+    shutil.copytree(HARNESS, root / "bench_harness",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    h = root / "bench_harness"
+    cfg = json.loads((h / "configs" / "epsilon.json").read_text())
+    del cfg["wire"]
+    cfg.update(rows=100_000, layout="dense", label_rate=0.5, margin_std=1.0,
+               precision="high", control={"precision": "fast"},
+               reduced={"rows": "one rank of four"},
+               rehearsal={"rows": 300, "features": 32, "method": "pallas"})
+    (h / "configs" / "epsilon_dense.json").write_text(json.dumps(cfg))
+    (h / "limits" / "epsilon_wide_build.json").write_text(
+        (h / "limits" / "higgs_level.json").read_text())
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append(dict(bench["configs"][1], name="epsilon_dense",
+                                 file="bench_harness/configs/epsilon_dense.json",
+                                 reduced=["rows"]))
+    bench["workloads"].append({"name": "epsilon_wide_build",
+                               "config": "epsilon_dense", "traffic": "level",
+                               "chips": 1, "why": "t"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "higgs_level" in m.get("workloads", []) and \
+                m["name"] != "round_ms_p95.level":
+            m["workloads"].append("epsilon_wide_build")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+@pytest.mark.parametrize("case", ["sound", "control", "_hist_drop_half",
+                                  "_hist_alter"])
+def test_dense_cell_rehearses(tmp_path, monkeypatch, case):
+    """The dense cell runs through the histogram entry with the stand-in
+    underneath, reports rows_per_s and setup_s, and comes out correct;
+    its check refuses the control and the planted faults."""
+    import importlib.util
+    from rabit_tpu.models import histogram as H
+    _add_dense_cell(tmp_path)
+    spec = importlib.util.spec_from_file_location(
+        "dense_bench_run", tmp_path / "bench_harness" / "run.py")
+    R = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(R)
+    f = _dense_as_entries(H.distributed_histogram)
+    if case.startswith("_hist"):
+        f = globals()[case](f)
+    monkeypatch.setattr(H, "distributed_histogram", f)
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    out = R.run("epsilon_wide_build", SEED, 0.5, False, rehearse=True,
+                control=case == "control")
+    assert out["correct"] is (case == "sound")
+    if case == "sound":
+        assert set(out["metrics"]) == {"rows_per_s", "setup_s"}
+        assert out["metrics"]["rows_per_s"]["value"] > 0
+
+
+def test_dense_layout_is_entries_reshaped():
+    """For one seed the dense inputs are the entries inputs reshaped and
+    un-broadcast, and the reference gives both the same histogram, in
+    one block of rows or in many."""
+    import functools
+    import jax
+    from bench_harness.entries.histogram import LAYOUTS, _gen
+    from bench_harness.run import seed_key
+    f = 48
+    kw = dict(sizes=(37, 300), features=f, max_bin=256, label_rate=0.5,
+              margin_std=1.0, pool=2)
+    (eb, eg, eh), (db, dg, dh) = (
+        jax.device_get(jax.jit(functools.partial(_gen, layout=lay, **kw))(
+            seed_key(SEED))) for lay in LAYOUTS)
+    for j, n in enumerate(kw["sizes"]):
+        assert db[j].shape == (1, n, f) and dg[0][j].shape == (1, n)
+        assert np.array_equal(db[j].reshape(1, -1), eb[j])
+        for k in range(kw["pool"]):
+            for e, d in ((eg, dg), (eh, dh)):
+                assert np.array_equal(np.repeat(d[k][j], f, axis=1), e[k][j])
+            want = reference.histogram(eb[j], eg[k][j], eh[k][j], f * 256)
+            got = reference.histogram(db[j], dg[k][j], dh[k][j], f * 256)
+            assert np.array_equal(got, want)
+            np.testing.assert_allclose(
+                reference.histogram(db[j], dg[k][j], dh[k][j], f * 256,
+                                    block_rows=16), want, rtol=1e-12)
+
+
+def _histogram_cell(workload: str, **cfg):
+    from bench_harness import run as R
+    from bench_harness.entries import histogram as E
+    from rabit_tpu.parallel import make_mesh
+    cell = R.load_cell(workload, rehearse=True)
+    return E.build({**cell["config"], **cfg}, cell["traffic"],
+                   R.seed_key(SEED), np.random.default_rng([SEED, 0]),
+                   make_mesh(1))
+
+
+# sha256 of the entries layout's node arrays and row counts at the
+# rehearsal sizes and SEED, taken before the dense layout came: adding it
+# left every input of the existing cells as it was, to the bit
+ENTRIES_DIGESTS = {
+    "higgs_level":
+        "1fafc98f59ecc5acaa50f7c59036bb92134e910c1281c42b0f475813d860a573",
+    "higgs_deep_nodes":
+        "e29ffe6b58f9054f09abad6e83c4f050c7f1636c0614f197657a14529ac9f56f",
+}
+
+
+@pytest.mark.parametrize("workload", sorted(ENTRIES_DIGESTS))
+def test_entries_layout_inputs_unchanged(workload):
+    import hashlib
+    c = _histogram_cell(workload)
+    h = hashlib.sha256()
+    for node in c.nodes:
+        for a in node:
+            a = np.asarray(a)
+            h.update(repr((a.shape, a.dtype.str)).encode())
+            h.update(a.tobytes())
+    h.update(repr(c.rows).encode())
+    assert h.hexdigest() == ENTRIES_DIGESTS[workload]
+
+
+@pytest.mark.parametrize("layout", ["entries", "dense"])
+def test_recorded_bytes(monkeypatch, layout):
+    """Each call records the bytes of the inputs it hands: 12 B per entry
+    in the entries layout, 4 B per entry + 8 B per row in the dense."""
+    from bench_harness import run as R
+    from rabit_tpu.models import histogram as H
+    monkeypatch.setattr(H, "distributed_histogram",
+                        _dense_as_entries(H.distributed_histogram))
+    c = _histogram_cell("higgs_deep_nodes", layout=layout)
+    rec = {}
+    nodes = [i for i, _ in c.round(0, rec, R._annotator(False))]
+    f = c.features
+    per_row = 12 * f if layout == "entries" else 4 * f + 8
+    assert rec["bytes"] == [c.rows[i] * per_row for i in nodes]
+
+
+def test_unknown_layout_refused():
+    with pytest.raises(ValueError, match="layout"):
+        _histogram_cell("higgs_level", layout="csr")
+
+
 # -- planted faults: the timed path broken underneath -------------------------
 
 def _inproc(monkeypatch, workload, target, attr, wrap):
@@ -207,8 +367,16 @@ def test_fault_fails_check(monkeypatch, workload, target, attr, wrap):
 # -- work counts, peaks, reference --------------------------------------------
 
 def test_hist_bytes_hand_checked():
-    # 36,750,000 entries x (4 + 4 + 4) B + 7,168 bins x 2 x 4 B
-    assert work.hist_bytes(36_750_000, 7168) == 441_000_000 + 57_344
+    # higgs_level, entries: 36,750,000 entries x (4 + 4 + 4) B
+    # + 7,168 bins x 2 x 4 B
+    assert work.hist_bytes(36_750_000 * 12, 7168) == 441_000_000 + 57_344
+    # one rank of epsilon, dense: 100,000 x 2,000 entries x 4 B
+    # + 100,000 rows x (4 + 4) B + 512,000 bins x 2 x 4 B,
+    # 0.983 ms at the v5e's 819 GB/s
+    nbytes = work.hist_bytes(100_000 * 2_000 * 4 + 100_000 * 8, 512_000)
+    assert nbytes == 804_896_000
+    hbm = peaks.lookup("TPU v5 lite")["hbm_bytes_per_s"]
+    assert round(nbytes / hbm * 1e3, 3) == 0.983
 
 
 def test_allreduce_bus_bytes_hand_checked():
@@ -245,6 +413,10 @@ def test_reference_histogram_and_rel_err():
     got = reference.histogram(bins, np.array([1., 2., 3., 4.]),
                               np.ones(4), 3)
     assert got.tolist() == [[1, 1], [4, 1], [5, 2]]
+    # dense: two rows of two entries, each row's weight on both
+    dense = reference.histogram(np.array([[[0, 2], [2, 1]]]),
+                                np.array([[1., 3.]]), np.ones((1, 2)), 3)
+    assert dense.tolist() == [[1, 1], [3, 1], [4, 2]]
     assert reference.rel_err(got, got) == 0
     assert reference.rel_err(got[:2], got) == float("inf")
     assert reference.rel_err(got * np.nan, got) == float("inf")
