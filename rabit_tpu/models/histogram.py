@@ -12,11 +12,19 @@ histogram on device and reduces it over the mesh:
   scatter throughput.
 - ``method="scatter"``: ``segment_sum`` — less memory traffic for very
   large bin counts.
+
+Two input forms. The flat form hands one (bin, grad, hess) triple per
+entry. The dense form is XGBoost's dense data: ``bins`` int32 ``[n, F]``
+of global ids ``f*max_bin + u`` (``max_bin = nbins // F``) with one
+(grad, hess) pair per row, counted on every one of the row's F entries;
+that ``bins`` has one axis more than ``grad`` is the whole signal. Both
+give the same ``[nbins, 2]`` histogram.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -39,19 +47,46 @@ def resolve_method(method: str) -> str:
     return "pallas" if pallas_available() else "scatter"
 
 
+def _dense_features(grad, bins, nbins: int) -> int:
+    """F of the dense form (``bins`` one axis more than ``grad``), or 0
+    for the flat form; refuses an ``nbins`` that is not F * max_bin."""
+    if bins.ndim != grad.ndim + 1:
+        return 0
+    nfeat = bins.shape[-1]
+    if nbins % nfeat:
+        raise ValueError(f"dense bins: nbins {nbins} is not a multiple of "
+                         f"the {nfeat} features")
+    return nfeat
+
+
 def local_histogram(grad: jax.Array, hess: jax.Array, bins: jax.Array,
                     nbins: int, method: str = "auto",
                     precision: str = "high") -> jax.Array:
     """Per-worker histogram: returns [nbins, 2] with (sum_g, sum_h) per bin.
 
-    ``bins`` is int32 [n] of flattened (feature, bucket) ids in
-    [0, nbins). Methods: "pallas" (MXU one-hot kernel, TPU only),
-    "matmul" (XLA scan of one-hot matmuls), "scatter" (segment_sum,
-    exact), "auto" (pallas on TPU else scatter). ``precision`` selects
-    the pallas accumulation: "high" (default, ~f32 accuracy) or "fast"
-    (single bf16 dot, ~2e-4 rel err — an explicit perf opt-in).
+    Flat form: ``bins`` int32 [n] of flattened (feature, bucket) ids in
+    [0, nbins), ``grad``/``hess`` [n]. Dense form: ``bins`` int32 [n, F]
+    of global ids f*max_bin + u, max_bin = nbins // F (a ValueError
+    where F does not divide nbins), ``grad``/``hess`` [n], each row's
+    pair on every one of its F entries; the result is the flat form's of
+    the same entries. Methods: "pallas" (MXU one-hot kernel, TPU only;
+    the dense form gets its own kernel, in which each entry meets only
+    its own feature's bins), "matmul" (XLA scan of one-hot matmuls),
+    "scatter" (segment_sum, exact), "auto" (pallas on TPU else
+    scatter); matmul and scatter take the dense form flattened.
+    ``precision`` selects the pallas accumulation: "high" (default, ~f32
+    accuracy) or "fast" (single bf16 dot, ~2e-4 rel err — an explicit
+    perf opt-in).
     """
     method = resolve_method(method)
+    if _dense_features(grad, bins, nbins):
+        if method == "pallas":
+            from ..ops.pallas_kernels import histogram_dense_tpu
+            return histogram_dense_tpu(bins, grad, hess, nbins,
+                                       precision=precision)
+        grad, hess = (jnp.broadcast_to(v[:, None], bins.shape).reshape(-1)
+                      for v in (grad, hess))
+        bins = bins.reshape(-1)
     if method == "pallas":
         from ..ops.pallas_kernels import histogram_tpu, _CHUNK
         n = grad.shape[0]
@@ -101,15 +136,23 @@ def distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
     """Build local histograms on every mesh device and allreduce them.
 
     Inputs have a leading worker axis sharded over ``axis``:
-    grad/hess [p, n_local], bins [p, n_local]. Output [nbins, 2]
-    replicated — the allreduced histogram every worker needs to find the
-    best split.
+    grad/hess [p, n_local], and bins [p, n_local] (flat form) or
+    [p, n_local, F] (dense form: global ids f*max_bin + u with
+    max_bin = nbins // F, a ValueError where F does not divide nbins;
+    each row's pair counted on all F of its entries). Output [nbins, 2]
+    replicated, the same for both forms of the same entries — the
+    allreduced histogram every worker needs to find the best split.
 
     The host call is the phase ``rabit.hist.enqueue``
-    (``telemetry.phase``); ``distributed_histogram.lower`` lowers the
-    jitted program.
+    (``telemetry.phase``), whose bytes are those of the three arrays
+    handed; ``distributed_histogram.lower`` lowers the jitted program.
     """
-    with telemetry.phase("rabit.hist.enqueue"):
+    with telemetry.phase("rabit.hist.enqueue") as ph:
+        if ph.live:
+            # shape arithmetic: fewer Python calls than .nbytes, each of
+            # which a profiler session's Python tracer charges
+            ph.nbytes = sum(math.prod(a.shape) * a.dtype.itemsize
+                            for a in (grad, hess, bins))
         return _distributed_histogram(grad, hess, bins, nbins, mesh, axis,
                                       method, precision)
 
@@ -145,12 +188,25 @@ distributed_histogram.lower = _distributed_histogram.lower
 
 
 def host_histogram(grad: np.ndarray, hess: np.ndarray, bins: np.ndarray,
-                   nbins: int) -> np.ndarray:
+                   nbins: int, block_rows: int = 4096) -> np.ndarray:
     """Numpy reference (also the CPU baseline the reference library would
-    feed its socket allreduce): [nbins, 2]."""
+    feed its socket allreduce): [nbins, 2], summed in float64.
+
+    Flat form: ``bins``, ``grad``, ``hess`` [n]. Dense form: ``bins``
+    [n, F] and ``grad``, ``hess`` [n], each row's pair on all F of its
+    entries, summed over blocks of ``block_rows`` rows so that no [n, F]
+    float64 copy of the weights is made."""
     out = np.zeros((nbins, 2), dtype=np.float64)
-    np.add.at(out[:, 0], bins, grad.astype(np.float64))
-    np.add.at(out[:, 1], bins, hess.astype(np.float64))
+    if bins.ndim == grad.ndim:
+        np.add.at(out[:, 0], bins, grad.astype(np.float64))
+        np.add.at(out[:, 1], bins, hess.astype(np.float64))
+        return out.astype(np.float32)
+    nfeat = bins.shape[-1]
+    for a in range(0, len(bins), block_rows):
+        b = bins[a:a + block_rows].reshape(-1)
+        for c, v in enumerate((grad, hess)):
+            w = np.repeat(v[a:a + block_rows].astype(np.float64), nfeat)
+            out[:, c] += np.bincount(b, w, nbins)
     return out.astype(np.float32)
 
 

@@ -189,6 +189,142 @@ def histogram_tpu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                                _interpret())
 
 
+# ---------------------------------------------------------------------------
+# Dense build: XGBoost's dense data form, bins [n, F] of global ids
+# f*max_bin + u with ONE (grad, hess) pair per row. The flat kernel above
+# cannot tell an entry's feature, so on wide data it tests every entry
+# against every bin (512,000 at epsilon's 2,000 x 256: 4,000 hi groups, 8
+# a-tiles, the rows read 8 times). Here the grid walks feature tiles x
+# row chunks and each entry meets only its own feature's max_bin bins,
+# through the same two-level split (u = hi*128 + lo, hi < H =
+# ceil(max_bin / 128)) with the values fused into the narrow hi side:
+#
+# - the kernel reads the bins as [F, n] (features on sublanes, rows on
+#   lanes), so that a feature's ids are one row. That is the TPU's own
+#   layout of a wide [n, F] int32 array (minor dim n: it pads n to 128,
+#   not F), so the transpose before the kernel is a bitcast, not a copy;
+# - per feature: the lo one-hot [128, chunk] (lo values on sublanes) and
+#   the fused [r*H, chunk] (row k*H + h: component k where hi == h), one
+#   NT dot -> [r*H, 128], accumulated into the feature's rows of the
+#   resident output block [ftile, r*H, 128];
+# - the weights arrive as [r*H, n_pad] (each component H times, so the
+#   fused operand is one select against a row-index constant), read once
+#   per row chunk and shared by every feature of the tile; they are never
+#   broadcast to F entries in HBM;
+# - nothing pads or copies the bins: the row tail and a last feature tile
+#   past F are partial blocks. Rows past n carry zero weights (the
+#   [r*H, n] weights are padded, not the bins); a feature row past F
+#   yields only its own output rows, which lie past F and are sliced
+#   off; an id outside its feature's [0, H*128) matches no hi.
+# ---------------------------------------------------------------------------
+
+_DENSE_CHUNK = 2048    # rows per grid step
+_DENSE_FTILE = 128     # features per grid step
+_DENSE_LO_BITS = 7     # lo one-hot of 1 << 7 = 128 bins: one lane tile
+# the dense build kernel's name on a device trace
+HIST_DENSE_KERNEL_NAME = "rabit_hist_build_dense"
+
+
+def _hist_dense_kernel_body(rh: int, hdim: int, cbits: int, max_bin: int,
+                            ftile: int, chunk: int, b_ref, w_ref, out_ref):
+    from jax.experimental import pallas as pl
+
+    j = pl.program_id(0)   # feature tile (outer)
+    i = pl.program_id(1)   # row chunk (inner: out block stays resident)
+
+    @pl.when(i == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    cdim = 1 << cbits
+    w = w_ref[:]                                          # f32 [rh, chunk]
+    iota_c = jax.lax.broadcasted_iota(jnp.int32, (cdim, chunk), 0)
+    hsel = jax.lax.rem(
+        jax.lax.broadcasted_iota(jnp.int32, (rh, chunk), 0), hdim)
+    iota_f = jax.lax.broadcasted_iota(jnp.int32, (8, 1), 0)
+
+    def group(g, carry):
+        # eight features a step: one aligned load of their id rows, made
+        # feature-local (u = id - f*max_bin)
+        f8 = pl.multiple_of(g * 8, 8)
+        u8 = b_ref[pl.ds(f8, 8), :] - (iota_f + j * ftile + f8) * max_bin
+        for t in range(8):
+            u = u8[t:t + 1, :]                            # [1, chunk]
+            lo = jax.lax.bitwise_and(u, cdim - 1)
+            hi = jax.lax.shift_right_arithmetic(u, cbits)  # < 0: no match
+            onehot = (lo == iota_c).astype(jnp.bfloat16)  # [cdim, chunk]
+            fused = jnp.where(hi == hsel, w, 0.0).astype(jnp.bfloat16)
+            out_ref[f8 + t] += jax.lax.dot_general(
+                fused, onehot, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [rh, cdim]
+        return carry
+
+    jax.lax.fori_loop(0, ftile // 8, group, 0)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("max_bin", "precision", "interpret"))
+def _histogram_dense_impl(bins, grad, hess, max_bin, precision, interpret):
+    from jax.experimental import pallas as pl
+
+    n, nfeat = bins.shape
+    if precision == "high":
+        # the flat kernel's hi/lo bf16 split (barrier: see
+        # _histogram_tpu_impl)
+        g_hi = jax.lax.optimization_barrier(
+            grad.astype(jnp.bfloat16)).astype(jnp.float32)
+        h_hi = jax.lax.optimization_barrier(
+            hess.astype(jnp.bfloat16)).astype(jnp.float32)
+        comps = (g_hi, h_hi, grad - g_hi, hess - h_hi)
+    else:
+        comps = (grad, hess)
+    r = len(comps)                                        # 2 or 4
+    cdim = 1 << _DENSE_LO_BITS
+    hdim = -(-max_bin // cdim)
+    rh = r * hdim
+    chunk = min(_DENSE_CHUNK, -(-n // 128) * 128)
+    nrc = -(-n // chunk)
+    ftile = min(_DENSE_FTILE, -(-nfeat // 8) * 8)
+    nft = -(-nfeat // ftile)
+    w = jnp.repeat(jnp.stack(comps), hdim, axis=0)        # [rh, n]
+    w = jnp.pad(w, ((0, 0), (0, nrc * chunk - n)))
+    out = pl.pallas_call(
+        functools.partial(_hist_dense_kernel_body, rh, hdim, _DENSE_LO_BITS,
+                          max_bin, ftile, chunk),
+        grid=(nft, nrc),
+        in_specs=[pl.BlockSpec((ftile, chunk), lambda j, i: (j, i)),
+                  pl.BlockSpec((rh, chunk), lambda j, i: (0, i))],
+        out_specs=pl.BlockSpec((ftile, rh, cdim), lambda j, i: (j, 0, 0)),
+        out_shape=_out_struct((nft * ftile, rh, cdim), jnp.float32,
+                              bins, grad, hess),
+        compiler_params=_hist_compiler_params(),
+        interpret=interpret,
+        name=HIST_DENSE_KERNEL_NAME,
+    )(bins.T, w)
+    # out[f, k*H + h, lo] -> [F, r, max_bin] -> [F*max_bin, 2]
+    out = out[:nfeat].reshape(nfeat, r, hdim * cdim)[:, :, :max_bin]
+    if precision == "high":
+        out = out[:, :2] + out[:, 2:]                     # hi + lo
+    return out.transpose(0, 2, 1).reshape(nfeat * max_bin, 2)
+
+
+def histogram_dense_tpu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
+                        nbins: int, precision: str = "high") -> jax.Array:
+    """Per-bin (sum_g, sum_h) [nbins, 2] of the dense form: ``bins`` int32
+    [n, F] of global ids f*max_bin + u (max_bin = nbins // F), ``grad``
+    and ``hess`` [n], each row's pair counted on every one of its F
+    entries. Any n and F; ``precision`` as in :func:`histogram_tpu`."""
+    if precision not in ("fast", "high"):
+        raise ValueError(f"precision must be 'fast' or 'high', "
+                         f"got {precision!r}")
+    nfeat = bins.shape[1]
+    if nbins % nfeat:
+        raise ValueError(f"nbins {nbins} is not a multiple of the "
+                         f"{nfeat} features")
+    return _histogram_dense_impl(bins, grad, hess, nbins // nfeat,
+                                 precision, _interpret())
+
+
 def pallas_available() -> bool:
     """Pallas TPU kernels only run on a real TPU backend."""
     try:

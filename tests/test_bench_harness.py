@@ -17,7 +17,8 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HARNESS_TESTS = os.path.join(ROOT, "bench_harness", "tests")
 
-_CELLS = ["higgs_level", "epsilon_tree_allreduce_4chip", "higgs_deep_nodes"]
+_CELLS = ["higgs_level", "epsilon_tree_allreduce_4chip", "higgs_deep_nodes",
+          "epsilon_wide_build"]
 # every harness test by its id; one added later runs under
 # test_unlisted_harness_tests_pass until it is listed here
 LISTED = (
@@ -27,6 +28,14 @@ LISTED = (
     + ["test_harness::test_refuses_cpu",
        "test_harness::test_refuses_without_the_program",
        "test_harness::test_new_cell_is_files_and_entries_only"]
+    + [f"test_harness::test_dense_cell_rehearses[{c}]"
+       for c in ("sound", "control", "_hist_drop_half", "_hist_alter")]
+    + ["test_harness::test_dense_layout_is_entries_reshaped"]
+    + [f"test_harness::test_entries_layout_inputs_unchanged[{w}]"
+       for w in ("higgs_deep_nodes", "higgs_level")]
+    + [f"test_harness::test_recorded_bytes[{lay}]"
+       for lay in ("entries", "dense")]
+    + ["test_harness::test_unknown_layout_refused"]
     + [f"test_harness::test_fault_fails_check[{w}-{f}]"
        for w in ("higgs_level", "higgs_deep_nodes")
        for f in ("_hist_drop_half", "_hist_alter")]

@@ -185,6 +185,31 @@ def test_profiler_session_records_one_tree_per_call(quiet, inputs,
     assert snap["spans"] == [] and snap["phases_dropped"] == 0
 
 
+@pytest.mark.parametrize("layout", ["flat", "dense"])
+def test_hist_enqueue_records_the_bytes_handed(quiet, layout):
+    """rabit.hist.enqueue's bytes are those of the three arrays handed,
+    in the flat form ([p, n*F] each) and the dense one (bins [p, n, F],
+    weights [p, n])."""
+    mesh = make_mesh(P)
+    rng = np.random.default_rng(1)
+    n, nfeat, max_bin = 300, 6, 16
+    bins = np.arange(nfeat) * max_bin + rng.integers(0, max_bin,
+                                                     (P, n, nfeat))
+    g, h = rng.standard_normal((2, P, n)).astype(np.float32)
+    if layout == "flat":
+        g, h = (np.repeat(v, nfeat, axis=1) for v in (g, h))
+        bins = bins.reshape(P, -1)
+    ghb = [shard_over(mesh, a) for a in (g, h, bins.astype(np.int32))]
+    telemetry.reset(enabled=True)
+    jax.block_until_ready(H.distributed_histogram(*ghb, nfeat * max_bin,
+                                                  mesh))
+    (ph,) = [p for p in telemetry.snapshot()["phases"]
+             if p["name"] == "rabit.hist.enqueue"]
+    per_row = 4 * nfeat * (3 if layout == "flat" else 1) + \
+        (0 if layout == "flat" else 8)
+    assert ph["bytes"] == sum(a.nbytes for a in ghb) == P * n * per_row
+
+
 def test_explicit_parent_and_thread_nesting(quiet, profiling):
     with telemetry.phase("a") as a:
         with telemetry.phase("b"):

@@ -86,6 +86,47 @@ def test_histogram_kernel_keeps_its_name(topo):
         [PK.HIST_KERNEL_NAME] == ["rabit_hist_build"]
 
 
+def _tpu_kernels(text):
+    names = re.findall(r"%([\w.]+) = .* custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', text)
+    return [re.sub(r"\.\d+$", "", k) for k in names]
+
+
+@pytest.mark.parametrize("precision", ["high", "fast"])
+def test_dense_histogram_compiles_without_copying_bins(topo, precision):
+    """distributed_histogram in the dense form at one epsilon rank
+    (100,000 rows x 2,000 features, 512,000 bins): its only kernel is the
+    dense build, and nothing pads, concatenates, copies or transposes the
+    [n, F] bins matrix (the TPU lays it out feature-major, which is how
+    the kernel reads it), so the program needs no temporary of its size;
+    the flat form still names the flat kernel."""
+    from rabit_tpu.models import histogram as H
+    n, nfeat = 100_000, 2_000
+    mesh = Mesh(topo.devices[:1], ("workers",))
+    sh = NamedSharding(mesh, P("workers"))
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,  # noqa: E731
+                                                  sharding=sh)
+    compiled = H.distributed_histogram.lower(
+        spec((1, n), jnp.float32), spec((1, n), jnp.float32),
+        spec((1, n, nfeat), jnp.int32), nbins=nfeat * 256, mesh=mesh,
+        method="pallas", precision=precision).compile()
+    text = compiled.as_text()
+    assert _tpu_kernels(text) == [PK.HIST_DENSE_KERNEL_NAME] == \
+        ["rabit_hist_build_dense"]
+    big = {f"[1,{n},{nfeat}]", f"[{n},{nfeat}]", f"[{nfeat},{n}]"}
+    moved = [ln.strip()[:200] for ln in text.splitlines()
+             if re.search(r" (pad|concatenate|copy|transpose)\(", ln)
+             and any(s in ln.split("=")[1].split("(")[0] for s in big)]
+    assert moved == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+    flat = lambda dt: spec((1, 3 * PK._CHUNK + 5), dt)  # noqa: E731
+    text = H.distributed_histogram.lower(
+        flat(jnp.float32), flat(jnp.float32), flat(jnp.int32),
+        nbins=28 * 256, mesh=mesh, method="pallas",
+        precision=precision).compile().as_text()
+    assert _tpu_kernels(text) == [PK.HIST_KERNEL_NAME] == ["rabit_hist_build"]
+
+
 @pytest.mark.parametrize("backward", [False, True],
                          ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("t", [512, 1024])
