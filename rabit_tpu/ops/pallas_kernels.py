@@ -6,33 +6,29 @@ per-row scatter-add the reference's use case feeds into its allreduce
 reformulates the scatter as masked matmuls on the MXU through a
 TWO-LEVEL bin decomposition, bin = hi*128 + lo:
 
-- out_k[a, c] = sum_rows [hi==a]*[lo==c]*gh_k: the [chunk, 128]
-  low-level one-hot (``lo == c``, full lane width) and the [chunk, A]
-  high-level one-hot need O(chunk * (A + 128)) compares instead of the
-  naive one-hot's O(chunk * nbins), and the dot's N dimension is
-  exactly one lane tile;
-- the component values fuse into whichever mask side is NARROWER
-  (hi side when A <= 128, e.g. 8 lanes at 1024 bins), so per-component
-  select work is O(chunk * min(A, 128)) and the value-free wide mask is
-  built once and shared by all components — fusing into the 128-wide
-  side made the 4-component high path ~9x slower than fast instead of
-  the expected ~2x;
+- entries on lanes: the flat [n] arrays reach the kernel as [n/128, 128]
+  (a bitcast of the TPU's 1D tiling), and a grid step takes its block
+  as one row of entries, [1, chunk];
+- the lo one-hot [128, chunk] (lo values on sublanes) and the hi match
+  [atile, chunk] cost O(128 + atile) compares an entry instead of the
+  naive one-hot's O(nbins); the component values fuse into the hi side,
+  [r*atile, chunk] (block k: component k where hi matches), with no lane
+  padding;
+- ONE NT dot [r*atile, chunk] x [128, chunk]^T -> [r*atile, 128] per
+  step stacks every component on the MXU;
+- the a-tile is sized from nbins and r so that r*atile <= 512: HIGGS's
+  7,168 bins (56 hi groups) is one a-tile; wider histograms take more
+  a-tiles, each reading the rows again;
 - default ``precision="high"``: gradients ride as four f32 components
-  (bf16 hi/lo splits of grad and hess) recombined after the kernel —
-  ~2e-6 relative accuracy at ~2x the fast path's per-component select
-  and dot work (4 components vs 2);
-- ``precision="fast"``: two components (grad, hess) cast to bf16 —
-  per-bin relative error ~2e-4 on 2M rows, inside split-finding
-  tolerance;
-- VMEM per grid step is O(chunk * 128) regardless of nbins (the naive
-  [chunk, nbins] mask OOM'd v5e's 16 MB scoped vmem at 1024 bins).
+  (bf16 hi/lo splits of grad and hess) recombined after the kernel,
+  ~1e-6 relative accuracy; ``precision="fast"``: two components (grad,
+  hess) cast to bf16, ~1e-3.
 
-Measured on v5e (2M rows, 1024 bins, dispatch-floor-cancelled slope
-timing with PRE-STAGED device inputs — see bench.py; earlier rounds
-timed in-loop threefry generation, ~2.8 ms/dataset, alongside the
-kernel): fast ~0.3 ms; the lo-side-fused high path ran ~3.0 ms, which
-motivated the narrow-side fusion; XLA ``segment_sum`` ~15 ms; the naive
-full-width one-hot kernel ran ~7 ms fast / OOM high.
+Measured on a TPU v5e (one HIGGS rank, 36,750,000 entries at 7,168
+bins, the median of 15 ``distributed_histogram`` calls, padding and
+hi/lo prep included): 16.47 ms high at the committed 16,384 entries a
+step, 16.78 ms at 8,192, 17.60 ms at 4,096, 19.05 ms at 2,048; fast
+10.61 ms at 8,192.
 """
 
 from __future__ import annotations
@@ -44,8 +40,9 @@ import jax.numpy as jnp
 import numpy as np
 
 
-_CHUNK = 16384   # rows per grid step
-_ATILE = 512    # high-level bin groups per grid step (VMEM bound)
+_CHUNK = 16384   # entries per grid step
+_LANES = 128     # entries per row of a block; lo one-hot of 128 bins
+_FUSED_ROWS = 512   # bound on the fused operand's r*atile rows (MXU, VMEM)
 # the build kernel's name on a device trace (the HLO custom call takes
 # the pallas_call's name): part of the interface, read by the benchmark
 HIST_KERNEL_NAME = "rabit_hist_build"
@@ -63,10 +60,20 @@ def _out_struct(shape, dtype, *arrs):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _hist_kernel_body(r: int, cbits: int, atile: int, chunk: int, *refs):
+def _atiles(nbins: int, r: int) -> tuple[int, int]:
+    """(atile, nat): hi groups per a-tile, a multiple of 8, and the
+    number of a-tiles, so that the fused operand's r*atile rows stay
+    within _FUSED_ROWS: 7,168 bins is one a-tile of 56, 16,640 two of 72
+    at r = 4."""
+    adim = -(-nbins // _LANES)
+    nat = -(-adim // (_FUSED_ROWS // r))
+    return -(-adim // (8 * nat)) * 8, nat
+
+
+def _hist_kernel_body(r: int, atile: int, chunk: int, b_ref, *refs):
     from jax.experimental import pallas as pl
 
-    b_ref, comp_refs, out_ref = refs[0], refs[1:1 + r], refs[1 + r]
+    comp_refs, out_ref = refs[:r], refs[r]
     j = pl.program_id(0)   # a-tile (outer)
     i = pl.program_id(1)   # row chunk (inner: out block stays resident)
 
@@ -74,50 +81,39 @@ def _hist_kernel_body(r: int, cbits: int, atile: int, chunk: int, *refs):
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    cdim = 1 << cbits                                # 128: one lane tile
-    bb = b_ref[:]                                    # [chunk] int32
-    hi_id = jax.lax.shift_right_logical(bb, cbits)   # bin = hi*C + lo
-    lo_id = jax.lax.bitwise_and(bb, cdim - 1)
-    iota_c = jax.lax.broadcasted_iota(jnp.int32, (chunk, cdim), 1)
-    lo_match = lo_id[:, None] == iota_c              # [chunk, 128] bool
-    a0 = j * atile
-    iota_a = jax.lax.broadcasted_iota(jnp.int32, (chunk, atile), 1) + a0
-    h_match = hi_id[:, None] == iota_a               # [chunk, atile] bool
-    # hist factorizes through the two-level decomposition:
-    # out_k[a, c] = sum_rows [hi==a] * [lo==c] * gh_k
-    # -> per component ONE [atile, chunk] x [chunk, 128] MXU dot, with
-    # compares O(chunk*(A+C)) instead of O(chunk*nbins). The component
-    # values fuse into WHICHEVER mask side is narrower — per-component
-    # elementwise work is O(chunk*min(A,C)) instead of always paying the
-    # full lane width (fusing into the 128-wide lo side cost the high
-    # path 4 [chunk, 128] selects/chunk and ~9x the fast path's time; at
-    # 1024 bins the hi side is 8 wide). Fusing value*mask stays exact in
-    # bf16: components are bf16-representable and the mask is 0/1. The
-    # value-free mask is built once and shared by all r components.
-    # (comp broadcast is f32 [chunk, 1] — Mosaic minor-dim insertion is
-    # 32-bit only)
-    hi_narrow = atile <= cdim
-    narrow, wide = (h_match, lo_match) if hi_narrow else (lo_match,
-                                                          h_match)
-    wide_bf = wide.astype(jnp.bfloat16)
-    for k in range(r):
-        col = comp_refs[k][:][:, None]               # f32 [chunk, 1]
-        fused = jnp.where(narrow, col, 0.0).astype(jnp.bfloat16)
-        # out is always [atile, cdim]: the hi-mask operand is the lhs
-        lhs, rhs = (fused, wide_bf) if hi_narrow else (wide_bf, fused)
-        out_ref[k] += jax.lax.dot_general(
-            lhs, rhs, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    def entries(ref):
+        # [chunk/128, 128] -> [1, chunk]: one row of entries
+        return ref[:].reshape(1, chunk)
+
+    cbits = _LANES.bit_length() - 1
+    bb = entries(b_ref)                              # int32 [1, chunk]
+    hi = jax.lax.shift_right_logical(bb, cbits)      # bin = hi*128 + lo
+    lo = jax.lax.bitwise_and(bb, _LANES - 1)
+    iota_c = jax.lax.broadcasted_iota(jnp.int32, (_LANES, chunk), 0)
+    iota_a = jax.lax.broadcasted_iota(jnp.int32, (atile, chunk), 0) \
+        + j * atile
+    # out[k*atile + a, c] = sum_entries [hi==a] * [lo==c] * comp_k, with
+    # the entries on lanes: the lo one-hot [128, chunk] (lo values on
+    # sublanes), the hi match [atile, chunk], and the fused [r*atile,
+    # chunk] (block k: comp_k where hi == a) for ONE NT dot. Fusing
+    # value*mask stays exact in bf16: components are bf16-representable,
+    # the mask 0/1.
+    onehot = (lo == iota_c).astype(jnp.bfloat16)
+    h_match = hi == iota_a
+    fused = jnp.concatenate(
+        [jnp.where(h_match, entries(c), 0.0) for c in comp_refs], axis=0)
+    out_ref[:] += jax.lax.dot_general(
+        fused.astype(jnp.bfloat16), onehot, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)          # [r*atile, 128]
 
 
 def _hist_compiler_params():
-    """Mosaic params for the histogram kernel. The narrow-side value
-    fusion holds r lane-padded [chunk, 128] component buffers live at
-    once, which overflows the default 16 MB *scoped* vmem budget on v5e
-    (measured: 21.8 MB fast / 28.6 MB high at chunk 16384) — raise it;
-    the chip has 128 MB physical VMEM and this kernel is the only
-    resident. The a-tile grid axis writes disjoint output blocks
-    (parallel); the row-chunk axis accumulates (arbitrary)."""
+    """Mosaic params of both histogram kernels: the a-tile (flat) or
+    feature-tile (dense) grid axis writes disjoint output blocks
+    (parallel), the row-chunk axis accumulates (arbitrary). The scoped
+    vmem budget is raised from v5e's default 16 MB to 64 MB (the chip
+    has 128 MB physical VMEM and the kernel is the only resident): the
+    flat kernel's fused operand is r*atile x chunk f32 before its cast."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"),
@@ -145,24 +141,24 @@ def _histogram_tpu_impl(bins, grad, hess, nbins, precision, interpret):
     else:
         comps = (grad, hess)
     r = len(comps)                                       # 2 or 4
-    cdim, cbits = 128, 7                                 # one lane tile
-    adim = -(-nbins // cdim)                             # ceil
-    atile = min(_ATILE, adim)
-    nat = -(-adim // atile)
-    a_pad = nat * atile
+    atile, nat = _atiles(nbins, r)
+    # [n] -> [n/128, 128]: a bitcast of the TPU's 1D tiling T(1024)
+    flat = [a.reshape(-1, _LANES) for a in (bins, *comps)]
     out = pl.pallas_call(
-        functools.partial(_hist_kernel_body, r, cbits, atile, _CHUNK),
+        functools.partial(_hist_kernel_body, r, atile, _CHUNK),
         grid=(nat, n // _CHUNK),
-        in_specs=[pl.BlockSpec((_CHUNK,), lambda j, i: (i,))] * (1 + r),
-        out_specs=pl.BlockSpec((r, atile, cdim), lambda j, i: (0, j, 0)),
-        out_shape=_out_struct((r, a_pad, cdim), jnp.float32,
+        in_specs=[pl.BlockSpec((_CHUNK // _LANES, _LANES),
+                               lambda j, i: (i, 0))] * (1 + r),
+        out_specs=pl.BlockSpec((r * atile, _LANES), lambda j, i: (j, 0)),
+        out_shape=_out_struct((nat * r * atile, _LANES), jnp.float32,
                               bins, grad, hess),
         compiler_params=_hist_compiler_params(),
         interpret=interpret,
         name=HIST_KERNEL_NAME,
-    )(bins, *comps)
-    # out[k, a, c] -> [r, a_pad*C] -> slice bins -> [nbins, 2]
-    comps = out.reshape(r, -1)[:, :nbins]
+    )(*flat)
+    # out[j*r*atile + k*atile + a, c] -> [r, nat*atile*128] -> [nbins, 2]
+    comps = out.reshape(nat, r, atile * _LANES).transpose(1, 0, 2) \
+        .reshape(r, -1)[:, :nbins]
     if precision == "high":
         comps = comps[:2] + comps[2:]                    # hi + lo
     return comps.T                                       # [nbins, 2]
@@ -193,10 +189,10 @@ def histogram_tpu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 # Dense build: XGBoost's dense data form, bins [n, F] of global ids
 # f*max_bin + u with ONE (grad, hess) pair per row. The flat kernel above
 # cannot tell an entry's feature, so on wide data it tests every entry
-# against every bin (512,000 at epsilon's 2,000 x 256: 4,000 hi groups, 8
-# a-tiles, the rows read 8 times). Here the grid walks feature tiles x
-# row chunks and each entry meets only its own feature's max_bin bins,
-# through the same two-level split (u = hi*128 + lo, hi < H =
+# against every bin (512,000 at epsilon's 2,000 x 256: 4,000 hi groups, 32
+# a-tiles at high precision, the rows read 32 times). Here the grid walks
+# feature tiles x row chunks and each entry meets only its own feature's
+# max_bin bins, through the same two-level split (u = hi*128 + lo, hi < H =
 # ceil(max_bin / 128)) with the values fused into the narrow hi side:
 #
 # - the kernel reads the bins as [F, n] (features on sublanes, rows on
@@ -455,9 +451,9 @@ def _flash_bwd_body(has_mask, sm_scale, *refs):
 def _flash_compiler_params():
     """Mosaic params for both flash block kernels. The backward holds ~5
     [T, S] f32 temporaries (s, p, dp, ds, tie mask) per head — past the
-    default 16 MB *scoped* vmem budget on v5e at T = S = 1024, the same
-    overflow that kept the histogram kernel's fused path from compiling
-    (see _hist_compiler_params). With the 64 MB limit below, compiling
+    default 16 MB *scoped* vmem budget on v5e at T = S = 1024 (the
+    histogram kernels raise it too, _hist_compiler_params). With the
+    64 MB limit below, compiling
     for a described v5e (H = 8, D = 128) shows the limit: T = S = 1024
     is the largest block shown to compile with the fused backward; at
     2048 the backward runs out of VMEM (RESOURCE_EXHAUSTED) while the

@@ -47,26 +47,47 @@ def test_local_histogram_padding():
 
 
 @pytest.mark.parametrize("precision", ["fast", "high"])
-@pytest.mark.parametrize("nbins", [64, 16640])
+@pytest.mark.parametrize("nbins", [64, 16640, 28 * 256])
 def test_local_histogram_pallas_interpret(monkeypatch, precision, nbins):
     """The pallas kernel (interpret mode on CPU) matches the host oracle
-    at its documented precision, including padding rows. nbins=64 takes
-    the values-fused-into-hi-mask branch (atile <= 128); nbins=16640
-    (130 hi-groups > one 128-lane tile) takes the lo-side branch."""
+    at its documented precision, including padding rows. nbins=64 is
+    fewer bins than one lo tile; 28*256 is HIGGS's one a-tile of 56 hi
+    groups; 16640 (130 hi groups) ends in a partial a-tile. Entries with
+    bin id nbins, planted among the real ones with large weights and
+    padding the tail to the chunk, contribute nothing."""
     monkeypatch.setenv("RABIT_PALLAS_INTERPRET", "1")
     from rabit_tpu.ops.pallas_kernels import histogram_tpu, _CHUNK
     n = 10_000
     grad, hess, bins = (a[0] for a in H.make_inputs(n, nbins, p=1, seed=5))
+    planted = np.random.default_rng(0).choice(n, 50, replace=False)
+    b, g, h = bins.copy(), grad.copy(), hess.copy()
+    b[planted], g[planted], h[planted] = nbins, 1e3, 1e3
     pad = (-n) % _CHUNK
-    b = np.concatenate([bins, np.full(pad, nbins, bins.dtype)])
-    g = np.concatenate([grad, np.zeros(pad, grad.dtype)])
-    h = np.concatenate([hess, np.zeros(pad, hess.dtype)])
+    assert pad
+    b = np.concatenate([b, np.full(pad, nbins, bins.dtype)])
+    g = np.concatenate([g, np.zeros(pad, grad.dtype)])
+    h = np.concatenate([h, np.zeros(pad, hess.dtype)])
     out = np.asarray(histogram_tpu(
         jnp.asarray(b), jnp.asarray(g), jnp.asarray(h), nbins,
         precision=precision))
-    want = H.host_histogram(grad, hess, bins, nbins)
+    keep = np.ones(n, bool)
+    keep[planted] = False
+    want = H.host_histogram(grad[keep], hess[keep], bins[keep], nbins)
     atol = 0.5 if precision == "fast" else 1e-3
     np.testing.assert_allclose(out, want, rtol=2e-2, atol=atol)
+
+
+@pytest.mark.parametrize("nbins, r, tiles", [
+    (64, 2, (8, 1)), (1024, 4, (8, 1)), (28 * 256, 4, (56, 1)),
+    (28 * 256, 2, (56, 1)), (16640, 4, (72, 2)), (16640, 2, (136, 1)),
+    (2000 * 256, 4, (128, 32))])
+def test_histogram_kernel_a_tiles(nbins, r, tiles):
+    """The flat kernel's a-tiles cover every hi group, in multiples of 8,
+    with a fused operand of at most 512 rows."""
+    from rabit_tpu.ops.pallas_kernels import _atiles
+    atile, nat = _atiles(nbins, r)
+    assert (atile, nat) == tiles
+    assert atile * nat * 128 >= nbins and r * atile <= 512
 
 
 def test_histogram_bad_precision_rejected(monkeypatch):

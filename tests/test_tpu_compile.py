@@ -92,6 +92,32 @@ def _tpu_kernels(text):
     return [re.sub(r"\.\d+$", "", k) for k in names]
 
 
+# Program temporaries of distributed_histogram at HIGGS's shape on a
+# described v5e, as the sublane-oriented flat kernel (commit 121f8b7)
+# compiled: the padded copies of the shard and of the hi/lo components.
+_HIGGS_TEMP_BYTES = {"high": 882_339_840, "fast": 588_214_272}
+
+
+@pytest.mark.parametrize("precision", ["high", "fast"])
+def test_flat_histogram_at_higgs_shape_adds_no_copy(topo, precision):
+    """distributed_histogram in the flat form at one HIGGS rank
+    (1,312,500 rows x 28 features, 7,168 bins): its one kernel is the
+    flat build, and the [n] -> [n/128, 128] view it takes adds no copy
+    of the 441 MB of inputs to the program's temporaries."""
+    from rabit_tpu.models import histogram as H
+    n = 1_312_500 * 28
+    mesh = Mesh(topo.devices[:1], ("workers",))
+    sh = NamedSharding(mesh, P("workers"))
+    row = lambda dt: jax.ShapeDtypeStruct((1, n), dt,  # noqa: E731
+                                          sharding=sh)
+    compiled = H.distributed_histogram.lower(
+        row(jnp.float32), row(jnp.float32), row(jnp.int32), nbins=28 * 256,
+        mesh=mesh, method="pallas", precision=precision).compile()
+    assert _tpu_kernels(compiled.as_text()) == [PK.HIST_KERNEL_NAME]
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        _HIGGS_TEMP_BYTES[precision]
+
+
 @pytest.mark.parametrize("precision", ["high", "fast"])
 def test_dense_histogram_compiles_without_copying_bins(topo, precision):
     """distributed_histogram in the dense form at one epsilon rank

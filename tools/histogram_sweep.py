@@ -1,8 +1,9 @@
 #!/usr/bin/env python
 """Bound the histogram kernel's remaining cost empirically (VERDICT r3
 #4): separate the fixed cost (HBM streaming + the two one-hot mask
-builds + one anchor dot) from the per-component cost (narrow-side value
-select + one MXU dot each) by sweeping three kernel variants:
+builds + one anchor dot) from the per-component cost (value select into
+the hi side + its rows of the one MXU dot) by sweeping three kernel
+variants:
 
 - ``mask_only``: builds both masks and does ONE value-free dot
   (bin-count histogram) — no per-component select work at all;
@@ -38,8 +39,8 @@ def main() -> None:
     from jax.experimental import pallas as pl
 
     from rabit_tpu.ops.pallas_kernels import (
-        _ATILE, _CHUNK, _hist_compiler_params, _interpret, _out_struct,
-        _histogram_tpu_impl)
+        _CHUNK, _LANES, _atiles, _hist_compiler_params, _interpret,
+        _out_struct, _histogram_tpu_impl)
 
     smoke = os.environ.get("RABIT_SWEEP_SMOKE") == "1"
     if smoke:
@@ -54,8 +55,9 @@ def main() -> None:
         K_SMALL, K_BIG = 2, 4
 
     def _mask_only_body(atile: int, chunk: int, b_ref, out_ref):
-        # the full kernel's mask construction verbatim, minus every
-        # per-component select: one value-free count dot
+        # the full kernel's mask construction verbatim (entries on
+        # lanes), minus every per-component select: one value-free
+        # count dot
         j = pl.program_id(0)
         i = pl.program_id(1)
 
@@ -63,37 +65,30 @@ def main() -> None:
         def _init():
             out_ref[:] = jnp.zeros_like(out_ref)
 
-        cdim, cbits = 128, 7
-        bb = b_ref[:]
-        hi_id = jax.lax.shift_right_logical(bb, cbits)
-        lo_id = jax.lax.bitwise_and(bb, cdim - 1)
-        iota_c = jax.lax.broadcasted_iota(jnp.int32, (chunk, cdim), 1)
-        lo_match = (lo_id[:, None] == iota_c).astype(jnp.bfloat16)
-        a0 = j * atile
-        iota_a = jax.lax.broadcasted_iota(jnp.int32, (chunk, atile), 1) + a0
-        h_match = (hi_id[:, None] == iota_a).astype(jnp.bfloat16)
-        out_ref[0] += jax.lax.dot_general(
-            h_match, lo_match, (((0,), (0,)), ((), ())),
+        bb = b_ref[:].reshape(1, chunk)
+        hi = jax.lax.shift_right_logical(bb, 7)
+        lo = jax.lax.bitwise_and(bb, _LANES - 1)
+        iota_c = jax.lax.broadcasted_iota(jnp.int32, (_LANES, chunk), 0)
+        iota_a = jax.lax.broadcasted_iota(
+            jnp.int32, (atile, chunk), 0) + j * atile
+        out_ref[:] += jax.lax.dot_general(
+            (hi == iota_a).astype(jnp.bfloat16),
+            (lo == iota_c).astype(jnp.bfloat16), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @functools.partial(jax.jit, static_argnames=("nbins",))
     def mask_only(bins, nbins):
-        cdim = 128
-        adim = -(-nbins // cdim)
-        atile = min(_ATILE, adim)
-        nat = -(-adim // atile)
+        atile, nat = _atiles(nbins, 1)
         out = pl.pallas_call(
             functools.partial(_mask_only_body, atile, _CHUNK),
             grid=(nat, bins.shape[0] // _CHUNK),
-            in_specs=[pl.BlockSpec((_CHUNK,), lambda j, i: (i,))],
-            out_specs=pl.BlockSpec((1, atile, cdim), lambda j, i: (0, j, 0)),
-            out_shape=_out_struct((1, nat * atile, cdim), jnp.float32, bins),
-            # same scoped-vmem budget as the full kernel: the two
-            # [chunk, lane] match masks + iotas alone flirt with the
-            # 16 MB default at chunk 16384 on v5e
+            in_specs=[pl.BlockSpec((_CHUNK // _LANES, _LANES),
+                                   lambda j, i: (i, 0))],
+            out_specs=pl.BlockSpec((atile, _LANES), lambda j, i: (j, 0)),
+            out_shape=_out_struct((nat * atile, _LANES), jnp.float32, bins),
             compiler_params=_hist_compiler_params(),
             interpret=_interpret(),
-        )(bins)
+        )(bins.reshape(-1, _LANES))
         return out.reshape(-1)[:nbins]
 
     @functools.partial(jax.jit, static_argnames=("nrows", "nbins"))

@@ -67,10 +67,9 @@ def main() -> None:
         os.replace(tmp, path)
 
     # --- histogram kernel (compiled Mosaic) -------------------------------
-    # nbins=1024 takes the values-fused-into-hi-mask branch (8 hi
-    # groups); nbins=16640 (130 groups > one lane tile) takes the
-    # lo-side branch — both must prove out compiled, not just in the
-    # CI interpret tests.
+    # nbins=1024 is one a-tile of 8 hi groups; nbins=16640 (130 groups)
+    # is two a-tiles at high precision, the last one partial — both
+    # must prove out compiled, not just in the CI interpret tests.
     from rabit_tpu.models import histogram as H
     n = 1 << 17 if smoke else 1 << 20
     for nbins in (1024, 16640):
