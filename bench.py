@@ -164,7 +164,7 @@ def main() -> None:
     data = jax.block_until_ready(gen_batch(7, n))
     variants = ([("pallas", "high"), ("pallas", "fast"),
                  ("scatter", "high")] if on_tpu
-                else [("matmul", "high"), ("scatter", "high")])
+                else [("scatter", "high")])
     # a variant that fails fails the run: no other variant may quietly
     # take the headline's place
     results = {}

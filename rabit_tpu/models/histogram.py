@@ -7,11 +7,11 @@ histogram across workers (the reference's motivating use case,
 doc/guide.md:137-143). The TPU-native design computes the local
 histogram on device and reduces it over the mesh:
 
-- ``method="matmul"``: one-hot × gradient matmul — keeps the FLOPs on
-  the MXU, the right trade on TPU where matmul throughput dwarfs
-  scatter throughput.
-- ``method="scatter"``: ``segment_sum`` — less memory traffic for very
-  large bin counts.
+- ``method="pallas"``: the Pallas kernels of ``ops/pallas_kernels.py``,
+  which turn the scatter into masked one-hot matmuls on the MXU (TPUs
+  have no hardware scatter).
+- ``method="scatter"``: ``segment_sum``, exact, on any backend.
+- ``method="auto"``: ``pallas`` on a TPU, ``scatter`` elsewhere.
 
 Two input forms. The flat form hands one (bin, grad, hess) triple per
 entry. The dense form is XGBoost's dense data: ``bins`` int32 ``[n, F]``
@@ -71,12 +71,11 @@ def local_histogram(grad: jax.Array, hess: jax.Array, bins: jax.Array,
     pair on every one of its F entries; the result is the flat form's of
     the same entries. Methods: "pallas" (MXU one-hot kernel, TPU only;
     the dense form gets its own kernel, in which each entry meets only
-    its own feature's bins), "matmul" (XLA scan of one-hot matmuls),
-    "scatter" (segment_sum, exact), "auto" (pallas on TPU else
-    scatter); matmul and scatter take the dense form flattened.
-    ``precision`` selects the pallas accumulation: "high" (default, ~f32
-    accuracy) or "fast" (single bf16 dot, ~2e-4 rel err — an explicit
-    perf opt-in).
+    its own feature's bins), "scatter" (segment_sum, exact, the dense
+    form flattened), "auto" (pallas on TPU else scatter); any other
+    name is a ValueError. ``precision`` selects the pallas
+    accumulation: "high" (default, ~f32 accuracy) or "fast" (single
+    bf16 dot, ~2e-4 rel err — an explicit perf opt-in).
     """
     method = resolve_method(method)
     if _dense_features(grad, bins, nbins):
@@ -88,45 +87,11 @@ def local_histogram(grad: jax.Array, hess: jax.Array, bins: jax.Array,
                       for v in (grad, hess))
         bins = bins.reshape(-1)
     if method == "pallas":
-        from ..ops.pallas_kernels import histogram_tpu, _CHUNK
-        n = grad.shape[0]
-        pad = (-n) % _CHUNK
-        if pad:
-            bins = jnp.concatenate(
-                [bins, jnp.full((pad,), nbins, bins.dtype)])
-            grad = jnp.concatenate([grad, jnp.zeros((pad,), grad.dtype)])
-            hess = jnp.concatenate([hess, jnp.zeros((pad,), hess.dtype)])
+        from ..ops.pallas_kernels import histogram_tpu
         return histogram_tpu(bins, grad, hess, nbins, precision=precision)
-    gh = jnp.stack([grad, hess], axis=1)  # [n, 2]
-    if method == "matmul":
-        # Chunk rows so the one-hot stays VMEM-sized; accumulate over
-        # chunks with scan (static trip count — jit-friendly). Padding
-        # rows get bin id == nbins, whose one_hot row is all-zero.
-        chunk = min(32768, max(1, gh.shape[0]))
-        n = gh.shape[0]
-        pad = (-n) % chunk
-        if pad:
-            bins = jnp.concatenate(
-                [bins, jnp.full((pad,), nbins, bins.dtype)])
-            gh = jnp.concatenate([gh, jnp.zeros((pad, 2), gh.dtype)])
-        bins_c = bins.reshape(-1, chunk)
-        gh_c = gh.reshape(-1, chunk, 2)
-
-        def chunk_hist(b, g):
-            onehot = jax.nn.one_hot(b, nbins, dtype=jnp.bfloat16)
-            return jnp.dot(onehot.T, g.astype(jnp.bfloat16),
-                           preferred_element_type=jnp.float32)
-
-        def body(acc, xs):
-            return acc + chunk_hist(*xs), None
-
-        # seed the carry with chunk 0 (not plain zeros) so it carries the
-        # same varying-manual-axes as the data under a checked shard_map
-        hist, _ = jax.lax.scan(
-            body, chunk_hist(bins_c[0], gh_c[0]), (bins_c[1:], gh_c[1:]))
-        return hist
     if method == "scatter":
-        return jax.ops.segment_sum(gh, bins, num_segments=nbins)
+        return jax.ops.segment_sum(jnp.stack([grad, hess], axis=1), bins,
+                                   num_segments=nbins)
     raise ValueError(f"unknown method {method!r}")
 
 
@@ -163,6 +128,7 @@ def distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
 def _distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
                            axis: str = "workers", method: str = "auto",
                            precision: str = "high") -> jax.Array:
+    method = resolve_method(method)
     use_ring = nbins * 2 >= RING_MINCOUNT_DEFAULT
 
     def per_shard(g, h, b):
@@ -174,11 +140,9 @@ def _distributed_histogram(grad, hess, bins, nbins: int, mesh: Mesh,
 
     # ring bodies need the replication checker off (ppermute chain), and
     # so does the pallas kernel (pallas_call's interpret evaluator is
-    # vma-inconsistent across its trace passes); matmul/scatter over the
-    # psum tree run fully checked
-    sm = (unchecked_shard_map
-          if use_ring or resolve_method(method) == "pallas"
-          else shard_map)
+    # vma-inconsistent across its trace passes); scatter over the psum
+    # tree runs fully checked
+    sm = unchecked_shard_map if use_ring or method == "pallas" else shard_map
     return sm(per_shard, mesh=mesh,
               in_specs=(P(axis), P(axis), P(axis)),
               out_specs=P())(grad, hess, bins)

@@ -121,25 +121,45 @@ def _hist_compiler_params():
     )
 
 
+def _check_precision(precision: str) -> None:
+    if precision not in ("fast", "high"):
+        raise ValueError(f"precision must be 'fast' or 'high', "
+                         f"got {precision!r}")
+
+
+def _components(grad, hess, precision: str) -> tuple:
+    """The weights as both kernels take them: (grad, hess) at "fast", and
+    at "high" their bf16 hi parts and f32 remainders (g_hi, h_hi, g_lo,
+    h_lo). Those stay f32 outside the kernels (a 1D f32 array needs no
+    lane padding) and are bf16-representable, so the kernels' casts are
+    exact."""
+    if precision != "high":
+        return grad, hess
+    # the barrier is load-bearing: under --xla_allow_excess_precision
+    # XLA folds the bf16 round-trip to identity, turning lo into exact
+    # zeros and silently degrading "high" to "fast"
+    g_hi = jax.lax.optimization_barrier(
+        grad.astype(jnp.bfloat16)).astype(jnp.float32)
+    h_hi = jax.lax.optimization_barrier(
+        hess.astype(jnp.bfloat16)).astype(jnp.float32)
+    return g_hi, h_hi, grad - g_hi, hess - h_hi
+
+
+def _recombined(parts, precision: str):
+    """[..., r, m] sums per component -> [..., 2, m] (grad, hess): at
+    "high" the hi and lo components' sums added."""
+    if precision != "high":
+        return parts
+    return parts[..., :2, :] + parts[..., 2:, :]
+
+
 @functools.partial(jax.jit,
                    static_argnames=("nbins", "precision", "interpret"))
 def _histogram_tpu_impl(bins, grad, hess, nbins, precision, interpret):
     from jax.experimental import pallas as pl
 
     n = bins.shape[0]
-    if precision == "high":
-        # the barrier is load-bearing: under --xla_allow_excess_precision
-        # XLA folds the bf16 round-trip to identity, turning lo into
-        # exact zeros and silently degrading "high" to "fast".
-        # components stay f32 on the wire (1D, no lane padding); the
-        # values are bf16-representable so the in-kernel cast is exact
-        g_hi = jax.lax.optimization_barrier(
-            grad.astype(jnp.bfloat16)).astype(jnp.float32)
-        h_hi = jax.lax.optimization_barrier(
-            hess.astype(jnp.bfloat16)).astype(jnp.float32)
-        comps = (g_hi, h_hi, grad - g_hi, hess - h_hi)
-    else:
-        comps = (grad, hess)
+    comps = _components(grad, hess, precision)
     r = len(comps)                                       # 2 or 4
     atile, nat = _atiles(nbins, r)
     # [n] -> [n/128, 128]: a bitcast of the TPU's 1D tiling T(1024)
@@ -159,28 +179,27 @@ def _histogram_tpu_impl(bins, grad, hess, nbins, precision, interpret):
     # out[j*r*atile + k*atile + a, c] -> [r, nat*atile*128] -> [nbins, 2]
     comps = out.reshape(nat, r, atile * _LANES).transpose(1, 0, 2) \
         .reshape(r, -1)[:, :nbins]
-    if precision == "high":
-        comps = comps[:2] + comps[2:]                    # hi + lo
-    return comps.T                                       # [nbins, 2]
+    return _recombined(comps, precision).T               # [nbins, 2]
 
 
 def histogram_tpu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                   nbins: int, precision: str = "high") -> jax.Array:
-    """Per-bin (sum_g, sum_h): [nbins, 2]. Rows whose bin id is >= nbins
-    (used for padding) contribute nothing. Requires len % _CHUNK == 0;
-    callers pad with bin id == nbins. ``precision``: "high" (default,
-    hi/lo split, ~2e-6 rel err) or "fast" (bf16 components, ~2e-4).
+    """Per-bin (sum_g, sum_h): [nbins, 2] of the flat form, ``bins``,
+    ``grad`` and ``hess`` [n], any n. Entries whose bin id is >= nbins
+    contribute nothing; the entries are padded to the kernel's step with
+    such ids and zero weights. ``precision``: "high" (default, hi/lo
+    split, ~2e-6 rel err) or "fast" (bf16 components, ~2e-4).
 
     The interpret flag is part of the jit key here, so flipping
     ``RABIT_PALLAS_INTERPRET`` between calls retraces correctly; a jit'd
     *caller* that traced this function resolves the flag at its own
     trace time."""
-    if precision not in ("fast", "high"):
-        raise ValueError(f"precision must be 'fast' or 'high', "
-                         f"got {precision!r}")
-    if bins.shape[0] % _CHUNK:
-        raise ValueError(f"row count {bins.shape[0]} not a multiple of "
-                         f"{_CHUNK}; pad with bin id == nbins")
+    _check_precision(precision)
+    pad = (-bins.shape[0]) % _CHUNK
+    if pad:
+        bins = jnp.concatenate([bins, jnp.full((pad,), nbins, bins.dtype)])
+        grad = jnp.concatenate([grad, jnp.zeros((pad,), grad.dtype)])
+        hess = jnp.concatenate([hess, jnp.zeros((pad,), hess.dtype)])
     return _histogram_tpu_impl(bins, grad, hess, nbins, precision,
                                _interpret())
 
@@ -264,16 +283,7 @@ def _histogram_dense_impl(bins, grad, hess, max_bin, precision, interpret):
     from jax.experimental import pallas as pl
 
     n, nfeat = bins.shape
-    if precision == "high":
-        # the flat kernel's hi/lo bf16 split (barrier: see
-        # _histogram_tpu_impl)
-        g_hi = jax.lax.optimization_barrier(
-            grad.astype(jnp.bfloat16)).astype(jnp.float32)
-        h_hi = jax.lax.optimization_barrier(
-            hess.astype(jnp.bfloat16)).astype(jnp.float32)
-        comps = (g_hi, h_hi, grad - g_hi, hess - h_hi)
-    else:
-        comps = (grad, hess)
+    comps = _components(grad, hess, precision)
     r = len(comps)                                        # 2 or 4
     cdim = 1 << _DENSE_LO_BITS
     hdim = -(-max_bin // cdim)
@@ -299,9 +309,8 @@ def _histogram_dense_impl(bins, grad, hess, max_bin, precision, interpret):
     )(bins.T, w)
     # out[f, k*H + h, lo] -> [F, r, max_bin] -> [F*max_bin, 2]
     out = out[:nfeat].reshape(nfeat, r, hdim * cdim)[:, :, :max_bin]
-    if precision == "high":
-        out = out[:, :2] + out[:, 2:]                     # hi + lo
-    return out.transpose(0, 2, 1).reshape(nfeat * max_bin, 2)
+    return _recombined(out, precision).transpose(0, 2, 1) \
+        .reshape(nfeat * max_bin, 2)
 
 
 def histogram_dense_tpu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
@@ -310,9 +319,7 @@ def histogram_dense_tpu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     [n, F] of global ids f*max_bin + u (max_bin = nbins // F), ``grad``
     and ``hess`` [n], each row's pair counted on every one of its F
     entries. Any n and F; ``precision`` as in :func:`histogram_tpu`."""
-    if precision not in ("fast", "high"):
-        raise ValueError(f"precision must be 'fast' or 'high', "
-                         f"got {precision!r}")
+    _check_precision(precision)
     nfeat = bins.shape[1]
     if nbins % nfeat:
         raise ValueError(f"nbins {nbins} is not a multiple of the "

@@ -2,8 +2,7 @@
 
 Each dispatch+fetch pays a fixed cost regardless of payload, so a
 per-call time of a short kernel measures that cost, not the device. The
-methodology (shared by bench.py, tools/kernel_hw_proof.py and
-tools/histogram_sweep.py — it was drifting as three copies):
+methodology (shared by bench.py and tools/kernel_hw_proof.py):
 
 - ``run_fn(k, salt)`` must run k work-iterations inside ONE jitted
   dispatch (a ``lax.fori_loop`` cycling pre-staged device inputs);

@@ -34,10 +34,6 @@ def _full_set(tmp_path, ts=FRESH_TS, backend="tpu"):
     _write(tmp_path, f"KERNEL_HW_{ts}.json",
            {"backend": backend, "complete": True,
             "flash_bwd_fused_vs_xla": {}, "timestamp_utc": ts})
-    _write(tmp_path, f"HIST_SWEEP_{ts}.json",
-           {"backend": backend, "timestamp_utc": ts})
-    _write(tmp_path, f"BOOSTED_BENCH_{ts}.json",
-           {"tpu": {"round_ms": 1}, "timestamp_utc": ts})
     _write(tmp_path, f"FLAGSHIP_HW_{ts}.json",
            {"backend": backend, "flash_attn": True, "timestamp_utc": ts})
     _write(tmp_path, f"FLAGSHIP_HW_{ts[:-3]}01Z.json",
@@ -68,10 +64,7 @@ def test_stale_artifacts_do_not_satisfy(tmp_path):
 def test_cpu_fallback_does_not_satisfy(tmp_path):
     mod = _load(tmp_path)
     _full_set(tmp_path, backend="cpu")
-    gaps = set(mod.missing())
-    # the two gates whose artifacts don't record a top-level backend
-    # (boosted tpu phase is None off-TPU by construction) are exempt
-    assert gaps >= set(mod.KNOWN) - {"boosted_tpu"}
+    assert set(mod.missing()) == set(mod.KNOWN)
 
 
 def test_incorrect_bench_does_not_satisfy(tmp_path):

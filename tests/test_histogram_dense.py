@@ -12,9 +12,8 @@ are exercised. Tolerances are relative to the largest bin sum:
   Pallas kernel at ``"fast"`` (one bf16 rounding of each weight) reads
   ~2e-3 and must fail it, so a path that silently computed in bf16
   would be caught.
-- BF16 (1e-2): paths that round each weight to bf16 once: the Pallas
-  kernel at ``"fast"`` and the ``matmul`` method, whose one-hot matmul is
-  bf16 whatever the precision.
+- BF16 (1e-2): the Pallas kernel at ``"fast"``, which rounds each
+  weight to bf16 once.
 """
 
 import numpy as np
@@ -66,17 +65,14 @@ def _run(mesh, grad, hess, bins, nbins, method, precision="high"):
 
 @pytest.mark.parametrize("p", [1, 4])
 @pytest.mark.parametrize("precision", ["high", "fast"])
-@pytest.mark.parametrize("method", ["scatter", "matmul", "pallas"])
+@pytest.mark.parametrize("method", ["scatter", "pallas"])
 def test_dense_matches_reference(monkeypatch, method, precision, p):
     monkeypatch.setenv("RABIT_PALLAS_INTERPRET", "1")
-    # matmul one-hots every entry against all nbins: a narrower shape
-    n, nfeat, max_bin = (300, 20, 64) if method == "matmul" else (N, F,
-                                                                  MAX_BIN)
-    grad, hess, bins = _dense(p, n, nfeat, max_bin, seed=p)
-    nbins = nfeat * max_bin
+    grad, hess, bins = _dense(p, seed=p)
+    nbins = F * MAX_BIN
     err = _rel_err(_run(make_mesh(p), grad, hess, bins, nbins, method,
                         precision), _want(grad, hess, bins, nbins))
-    bf16 = method == "matmul" or (method == "pallas" and precision == "fast")
+    bf16 = method == "pallas" and precision == "fast"
     assert err <= (BF16 if bf16 else TIGHT), err
     if method == "pallas" and precision == "fast":
         assert err > TIGHT, "fast must fail the tolerance high meets"

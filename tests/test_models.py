@@ -16,8 +16,9 @@ NDEV = len(jax.devices())
 pytestmark = pytest.mark.skipif(NDEV < 8, reason="needs 8 virtual devices")
 
 
-@pytest.mark.parametrize("method", ["matmul", "scatter"])
-def test_distributed_histogram(method):
+@pytest.mark.parametrize("method", ["pallas", "scatter"])
+def test_distributed_histogram(monkeypatch, method):
+    monkeypatch.setenv("RABIT_PALLAS_INTERPRET", "1")
     p, n, nbins = 8, 4096, 64
     grad, hess, bins = H.make_inputs(n, nbins, p=p, seed=3)
     mesh = make_mesh(p)
@@ -27,23 +28,71 @@ def test_distributed_histogram(method):
     want = np.zeros((nbins, 2), np.float64)
     for i in range(p):
         want += H.host_histogram(grad[i], hess[i], bins[i], nbins)
-    # matmul path reduces in bf16: error is absolute in the magnitude of
-    # per-bin sums (~sqrt(rows/bin)), so give it an absolute floor
-    if method == "matmul":
-        np.testing.assert_allclose(out, want, rtol=2e-2, atol=0.5)
-    else:
-        np.testing.assert_allclose(out, want, rtol=1e-3, atol=1e-3)
+    np.testing.assert_allclose(out, want, rtol=1e-3, atol=1e-3)
 
 
-def test_local_histogram_padding():
-    # n not divisible by the matmul chunk: padding rows must not leak
+def test_local_histogram_padding(monkeypatch):
+    # n not divisible by the kernel's step: padding entries must not leak
+    monkeypatch.setenv("RABIT_PALLAS_INTERPRET", "1")
     n, nbins = 1000, 16
     grad, hess, bins = (a[0] for a in H.make_inputs(n, nbins, p=1, seed=1))
     out = np.asarray(H.local_histogram(
         jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(bins), nbins,
-        method="matmul"))
+        method="pallas"))
     want = H.host_histogram(grad, hess, bins, nbins)
-    np.testing.assert_allclose(out, want, rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(out, want, rtol=1e-3, atol=1e-3)
+
+
+def test_histogram_tpu_pads_any_n(monkeypatch):
+    """The flat kernel's entry pads an unaligned n itself: bit for bit
+    the call on entries padded by hand with bin id nbins and zero
+    weights."""
+    monkeypatch.setenv("RABIT_PALLAS_INTERPRET", "1")
+    from rabit_tpu.ops.pallas_kernels import histogram_tpu, _CHUNK
+    n, nbins = 1000, 64
+    grad, hess, bins = (a[0] for a in H.make_inputs(n, nbins, p=1, seed=2))
+    pad = _CHUNK - n
+    padded = histogram_tpu(
+        jnp.asarray(np.concatenate([bins, np.full(pad, nbins, bins.dtype)])),
+        jnp.asarray(np.concatenate([grad, np.zeros(pad, grad.dtype)])),
+        jnp.asarray(np.concatenate([hess, np.zeros(pad, hess.dtype)])),
+        nbins)
+    got = histogram_tpu(jnp.asarray(bins), jnp.asarray(grad),
+                        jnp.asarray(hess), nbins)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(padded))
+
+
+@pytest.mark.parametrize("precision", ["high", "fast"])
+def test_components_split(precision):
+    """The weights both kernels take. At "high", under jit as the
+    kernels' callers run it: hi parts bf16-representable, hi + lo the
+    f32 input exactly, and lo non-zero wherever bf16 cannot hold the
+    input. An XLA allowed excess precision may fold the bf16 round-trip
+    and zero every lo, which the CPU backend does not do, so the program
+    must also keep its optimization barrier. At "fast", the inputs
+    themselves."""
+    from rabit_tpu.ops.pallas_kernels import _components
+    rng = np.random.default_rng(4)
+    grad = rng.standard_normal(4096).astype(np.float32)
+    hess = rng.random(4096).astype(np.float32)
+    split = jax.jit(_components, static_argnames="precision")
+    args = jnp.asarray(grad), jnp.asarray(hess)
+    assert ("optimization_barrier" in split.lower(
+        *args, precision=precision).as_text()) == (precision == "high")
+    comps = [np.asarray(c) for c in split(*args, precision=precision)]
+    if precision == "fast":
+        assert len(comps) == 2
+        np.testing.assert_array_equal(comps[0], grad)
+        np.testing.assert_array_equal(comps[1], hess)
+        return
+    assert len(comps) == 4
+    for x, hi, lo in ((grad, comps[0], comps[2]), (hess, comps[1], comps[3])):
+        np.testing.assert_array_equal(
+            hi.astype(jnp.bfloat16).astype(np.float32), hi)
+        np.testing.assert_array_equal(hi + lo, x)
+        inexact = x.astype(jnp.bfloat16).astype(np.float32) != x
+        assert inexact.sum() > 4000
+        assert np.all(lo[inexact] != 0)
 
 
 @pytest.mark.parametrize("precision", ["fast", "high"])

@@ -330,6 +330,9 @@ class _FakeStatsLib:
         r._obj.value, f._obj.value, s._obj.value = self.vals
         return self.rc
 
+    def RbtGetRank(self):  # noqa: N802 - C ABI name
+        return 0
+
 
 def _bare_engine(lib):
     from rabit_tpu.engine.native import NativeEngine

@@ -53,8 +53,8 @@ from rabit_tpu.telemetry.schema import make_header  # noqa: E402
 # that commit — capture time precedes commit time, not the fix.
 FRESH = "20260731"
 
-KNOWN = ("kernel_hw", "hist_sweep", "boosted_tpu", "flagship_flash",
-         "flagship_default", "wire_tpu", "bench_local")
+KNOWN = ("kernel_hw", "flagship_flash", "flagship_default", "wire_tpu",
+         "bench_local")
 
 
 def _arts(prefix):
@@ -94,13 +94,6 @@ def missing():
                 and a.get("complete") and "flash_bwd_fused_vs_xla" in a):
         gaps["kernel_hw"] = ("no complete current-kernel KERNEL_HW artifact "
                              "with the fused flash backward measured")
-
-    if not good("HIST_SWEEP", lambda a: a.get("backend") == "tpu"):
-        gaps["hist_sweep"] = "no current-kernel HIST_SWEEP artifact"
-
-    if not good("BOOSTED_BENCH", lambda a: a.get("tpu")):
-        gaps["boosted_tpu"] = ("no current-kernel BOOSTED_BENCH artifact "
-                               "with a tpu phase")
 
     # both flagship legs must run on the CURRENT kernel: a legacy
     # default-attention artifact would make the default-vs-flash
