@@ -6,9 +6,11 @@ per-row scatter-add the reference's use case feeds into its allreduce
 reformulates the scatter as masked matmuls on the MXU through a
 TWO-LEVEL bin decomposition, bin = hi*128 + lo:
 
-- entries on lanes: the flat [n] arrays reach the kernel as [n/128, 128]
-  (a bitcast of the TPU's 1D tiling), and a grid step takes its block
-  as one row of entries, [1, chunk];
+- entries on lanes: the kernel reads the flat form as handed, the
+  shard's [1, n] bins, grad and hess, and a grid step takes its block
+  as one row of entries, [1, chunk]. Nothing pads or copies the inputs
+  first: the last block runs past n, and its entries past n are masked
+  inside the kernel, so no input copy precedes it;
 - the lo one-hot [128, chunk] (lo values on sublanes) and the hi match
   [atile, chunk] cost O(128 + atile) compares an entry instead of the
   naive one-hot's O(nbins); the component values fuse into the hi side,
@@ -19,16 +21,14 @@ TWO-LEVEL bin decomposition, bin = hi*128 + lo:
 - the a-tile is sized from nbins and r so that r*atile <= 512: HIGGS's
   7,168 bins (56 hi groups) is one a-tile; wider histograms take more
   a-tiles, each reading the rows again;
-- default ``precision="high"``: gradients ride as four f32 components
-  (bf16 hi/lo splits of grad and hess) recombined after the kernel,
-  ~1e-6 relative accuracy; ``precision="fast"``: two components (grad,
-  hess) cast to bf16, ~1e-3.
+- default ``precision="high"``: the kernel splits each loaded block of
+  grad and hess into bf16 hi parts and f32 remainders, four components
+  recombined after the kernel, ~1e-6 relative accuracy;
+  ``precision="fast"``: two components (grad, hess) cast to bf16, ~1e-3.
 
-Measured on a TPU v5e (one HIGGS rank, 36,750,000 entries at 7,168
-bins, the median of 15 ``distributed_histogram`` calls, padding and
-hi/lo prep included): 16.47 ms high at the committed 16,384 entries a
-step, 16.78 ms at 8,192, 17.60 ms at 4,096, 19.05 ms at 2,048; fast
-10.61 ms at 8,192.
+On a TPU v5e the kernel builds one HIGGS rank's histogram (36,750,000
+entries at 7,168 bins, ``precision="high"``) in about 11.1 ms (the
+benchmark's ``hist_kernel_ms`` in its ``higgs_level`` cell).
 """
 
 from __future__ import annotations
@@ -41,7 +41,7 @@ import numpy as np
 
 
 _CHUNK = 16384   # entries per grid step
-_LANES = 128     # entries per row of a block; lo one-hot of 128 bins
+_LANES = 128     # lanes of a vreg; lo one-hot of 128 bins
 _FUSED_ROWS = 512   # bound on the fused operand's r*atile rows (MXU, VMEM)
 # the build kernel's name on a device trace (the HLO custom call takes
 # the pallas_call's name): part of the interface, read by the benchmark
@@ -70,25 +70,35 @@ def _atiles(nbins: int, r: int) -> tuple[int, int]:
     return -(-adim // (8 * nat)) * 8, nat
 
 
-def _hist_kernel_body(r: int, atile: int, chunk: int, b_ref, *refs):
+def _hist_kernel_body(n: int, precision: str, atile: int, chunk: int,
+                      b_ref, g_ref, h_ref, out_ref):
     from jax.experimental import pallas as pl
 
-    comp_refs, out_ref = refs[:r], refs[r]
     j = pl.program_id(0)   # a-tile (outer)
-    i = pl.program_id(1)   # row chunk (inner: out block stays resident)
+    i = pl.program_id(1)   # entry block (inner: out block stays resident)
 
     @pl.when(i == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    def entries(ref):
-        # [chunk/128, 128] -> [1, chunk]: one row of entries
-        return ref[:].reshape(1, chunk)
-
     cbits = _LANES.bit_length() - 1
-    bb = entries(b_ref)                              # int32 [1, chunk]
-    hi = jax.lax.shift_right_logical(bb, cbits)      # bin = hi*128 + lo
-    lo = jax.lax.bitwise_and(bb, _LANES - 1)
+    bb = b_ref[:]                                    # int32 [1, chunk]
+    # the last block runs past n into memory no caller wrote: there hi is
+    # -1, which matches no hi group, so the select that builds the fused
+    # operand drops those weights, whatever they hold (NaN included)
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) + i * chunk
+    hi = jnp.where(idx < n, jax.lax.shift_right_logical(bb, cbits), -1)
+    lo = jax.lax.bitwise_and(bb, _LANES - 1)         # bin = hi*128 + lo
+    g, h = g_ref[:], h_ref[:]                        # f32 [1, chunk]
+    comps = (g, h)
+    if precision == "high":
+        # the split of _components, made on the loaded block; no XLA
+        # excess-precision folding reaches a Mosaic body, so the bf16
+        # round-trip needs no barrier here (its truncf/extf pair is in
+        # the lowered kernel: tests/test_tpu_compile.py)
+        g_hi = g.astype(jnp.bfloat16).astype(jnp.float32)
+        h_hi = h.astype(jnp.bfloat16).astype(jnp.float32)
+        comps = (g_hi, h_hi, g - g_hi, h - h_hi)
     iota_c = jax.lax.broadcasted_iota(jnp.int32, (_LANES, chunk), 0)
     iota_a = jax.lax.broadcasted_iota(jnp.int32, (atile, chunk), 0) \
         + j * atile
@@ -96,12 +106,12 @@ def _hist_kernel_body(r: int, atile: int, chunk: int, b_ref, *refs):
     # the entries on lanes: the lo one-hot [128, chunk] (lo values on
     # sublanes), the hi match [atile, chunk], and the fused [r*atile,
     # chunk] (block k: comp_k where hi == a) for ONE NT dot. Fusing
-    # value*mask stays exact in bf16: components are bf16-representable,
-    # the mask 0/1.
+    # value*mask stays exact in bf16 for the hi parts, which are
+    # bf16-representable, the mask 0/1.
     onehot = (lo == iota_c).astype(jnp.bfloat16)
     h_match = hi == iota_a
     fused = jnp.concatenate(
-        [jnp.where(h_match, entries(c), 0.0) for c in comp_refs], axis=0)
+        [jnp.where(h_match, c, 0.0) for c in comps], axis=0)
     out_ref[:] += jax.lax.dot_general(
         fused.astype(jnp.bfloat16), onehot, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # [r*atile, 128]
@@ -128,11 +138,11 @@ def _check_precision(precision: str) -> None:
 
 
 def _components(grad, hess, precision: str) -> tuple:
-    """The weights as both kernels take them: (grad, hess) at "fast", and
-    at "high" their bf16 hi parts and f32 remainders (g_hi, h_hi, g_lo,
-    h_lo). Those stay f32 outside the kernels (a 1D f32 array needs no
-    lane padding) and are bf16-representable, so the kernels' casts are
-    exact."""
+    """The weights as the dense kernel takes them: (grad, hess) at
+    "fast", and at "high" their bf16 hi parts and f32 remainders (g_hi,
+    h_hi, g_lo, h_lo), in the order the flat kernel makes them from its
+    loaded block. They stay f32 outside the kernel (a 1D f32 array needs
+    no lane padding)."""
     if precision != "high":
         return grad, hess
     # the barrier is load-bearing: under --xla_allow_excess_precision
@@ -158,24 +168,23 @@ def _recombined(parts, precision: str):
 def _histogram_tpu_impl(bins, grad, hess, nbins, precision, interpret):
     from jax.experimental import pallas as pl
 
-    n = bins.shape[0]
-    comps = _components(grad, hess, precision)
-    r = len(comps)                                       # 2 or 4
+    # [n] -> [1, n]: on the [n] view of a [1, n] shard the two reshapes
+    # cancel, and the kernel reads the shard's arrays as handed
+    bins, grad, hess = (a.reshape(1, -1) for a in (bins, grad, hess))
+    n = bins.shape[1]
+    r = 4 if precision == "high" else 2
     atile, nat = _atiles(nbins, r)
-    # [n] -> [n/128, 128]: a bitcast of the TPU's 1D tiling T(1024)
-    flat = [a.reshape(-1, _LANES) for a in (bins, *comps)]
     out = pl.pallas_call(
-        functools.partial(_hist_kernel_body, r, atile, _CHUNK),
-        grid=(nat, n // _CHUNK),
-        in_specs=[pl.BlockSpec((_CHUNK // _LANES, _LANES),
-                               lambda j, i: (i, 0))] * (1 + r),
+        functools.partial(_hist_kernel_body, n, precision, atile, _CHUNK),
+        grid=(nat, pl.cdiv(n, _CHUNK)),
+        in_specs=[pl.BlockSpec((1, _CHUNK), lambda j, i: (0, i))] * 3,
         out_specs=pl.BlockSpec((r * atile, _LANES), lambda j, i: (j, 0)),
         out_shape=_out_struct((nat * r * atile, _LANES), jnp.float32,
                               bins, grad, hess),
         compiler_params=_hist_compiler_params(),
         interpret=interpret,
         name=HIST_KERNEL_NAME,
-    )(*flat)
+    )(bins, grad, hess)
     # out[j*r*atile + k*atile + a, c] -> [r, nat*atile*128] -> [nbins, 2]
     comps = out.reshape(nat, r, atile * _LANES).transpose(1, 0, 2) \
         .reshape(r, -1)[:, :nbins]
@@ -185,21 +194,15 @@ def _histogram_tpu_impl(bins, grad, hess, nbins, precision, interpret):
 def histogram_tpu(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                   nbins: int, precision: str = "high") -> jax.Array:
     """Per-bin (sum_g, sum_h): [nbins, 2] of the flat form, ``bins``,
-    ``grad`` and ``hess`` [n], any n. Entries whose bin id is >= nbins
-    contribute nothing; the entries are padded to the kernel's step with
-    such ids and zero weights. ``precision``: "high" (default, hi/lo
-    split, ~2e-6 rel err) or "fast" (bf16 components, ~2e-4).
+    ``grad`` and ``hess`` all [n] or all [1, n], any n. Entries whose bin
+    id is >= nbins contribute nothing. ``precision``: "high" (default,
+    hi/lo split, ~2e-6 rel err) or "fast" (bf16 components, ~2e-4).
 
     The interpret flag is part of the jit key here, so flipping
     ``RABIT_PALLAS_INTERPRET`` between calls retraces correctly; a jit'd
     *caller* that traced this function resolves the flag at its own
     trace time."""
     _check_precision(precision)
-    pad = (-bins.shape[0]) % _CHUNK
-    if pad:
-        bins = jnp.concatenate([bins, jnp.full((pad,), nbins, bins.dtype)])
-        grad = jnp.concatenate([grad, jnp.zeros((pad,), grad.dtype)])
-        hess = jnp.concatenate([hess, jnp.zeros((pad,), hess.dtype)])
     return _histogram_tpu_impl(bins, grad, hess, nbins, precision,
                                _interpret())
 

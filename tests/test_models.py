@@ -63,6 +63,37 @@ def test_histogram_tpu_pads_any_n(monkeypatch):
 
 
 @pytest.mark.parametrize("precision", ["high", "fast"])
+@pytest.mark.parametrize("n", [1, 127, 2688, 16383, 16385, 3 * 16384 + 5])
+def test_histogram_tpu_masks_its_last_block(monkeypatch, n, precision):
+    """The flat kernel reads unpadded inputs as handed and masks the
+    entries of its last block that lie past n: with interpret mode
+    filling them with bin 0 and NaN weights, it matches the host oracle
+    at sizes under one block, around one block and past three, and the
+    [n] and [1, n] forms give the same bits."""
+    from jax._src.pallas import primitives
+    monkeypatch.setenv("RABIT_PALLAS_INTERPRET", "1")
+    fill = primitives.uninitialized_value
+    monkeypatch.setattr(   # a bin in range, where INT_MIN is in none
+        primitives, "uninitialized_value",
+        lambda shape, dtype: (jnp.zeros(shape, dtype)
+                              if jnp.issubdtype(dtype, jnp.integer)
+                              else fill(shape, dtype)))
+    from rabit_tpu.ops.pallas_kernels import histogram_tpu
+    nbins = 28 * 256
+    grad, hess, bins = (a[0] for a in H.make_inputs(n, nbins, p=1, seed=6))
+    flat = np.asarray(histogram_tpu(
+        jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess), nbins,
+        precision=precision))
+    row = np.asarray(histogram_tpu(
+        *(jnp.asarray(a[None]) for a in (bins, grad, hess)), nbins,
+        precision=precision))
+    np.testing.assert_array_equal(flat, row)
+    want = H.host_histogram(grad, hess, bins, nbins)
+    atol = 0.1 if precision == "fast" else 1e-4
+    np.testing.assert_allclose(flat, want, rtol=0, atol=atol)
+
+
+@pytest.mark.parametrize("precision", ["high", "fast"])
 def test_components_split(precision):
     """The weights both kernels take. At "high", under jit as the
     kernels' callers run it: hi parts bf16-representable, hi + lo the

@@ -92,20 +92,26 @@ def _tpu_kernels(text):
     return [re.sub(r"\.\d+$", "", k) for k in names]
 
 
-# Program temporaries of distributed_histogram at HIGGS's shape on a
-# described v5e, as the sublane-oriented flat kernel (commit 121f8b7)
-# compiled: the padded copies of the shard and of the hi/lo components.
-_HIGGS_TEMP_BYTES = {"high": 882_339_840, "fast": 588_214_272}
+def _elements(hlo_type):
+    """Element count of each array in an HLO result type."""
+    return [int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            for dims in re.findall(r"\w+\[([\d,]*)\]", hlo_type)]
 
 
-@pytest.mark.parametrize("precision", ["high", "fast"])
-def test_flat_histogram_at_higgs_shape_adds_no_copy(topo, precision):
+@pytest.mark.parametrize("n, precision", [
+    (1_312_500 * 28, "high"), (1_312_500 * 28, "fast"),
+    (96 * 28, "high"), (96 * 28, "fast")],
+    ids=["high", "fast", "node-high", "node-fast"])
+def test_flat_histogram_at_higgs_shape_adds_no_copy(topo, n, precision):
     """distributed_histogram in the flat form at one HIGGS rank
-    (1,312,500 rows x 28 features, 7,168 bins): its one kernel is the
-    flat build, and the [n] -> [n/128, 128] view it takes adds no copy
-    of the 441 MB of inputs to the program's temporaries."""
+    (1,312,500 rows x 28 features, 7,168 bins) and at one node of 96 rows,
+    less than one kernel block: its one kernel is the flat build, which
+    takes the program's three [1, n] parameters as they are, and no op
+    copies, pads, converts or relayouts them, so the program's
+    temporaries hold no copy of the inputs (882,339,840 B at "high" and
+    588,214,272 B at "fast" when the program padded them and split hi/lo
+    outside the kernel)."""
     from rabit_tpu.models import histogram as H
-    n = 1_312_500 * 28
     mesh = Mesh(topo.devices[:1], ("workers",))
     sh = NamedSharding(mesh, P("workers"))
     row = lambda dt: jax.ShapeDtypeStruct((1, n), dt,  # noqa: E731
@@ -113,9 +119,53 @@ def test_flat_histogram_at_higgs_shape_adds_no_copy(topo, precision):
     compiled = H.distributed_histogram.lower(
         row(jnp.float32), row(jnp.float32), row(jnp.int32), nbins=28 * 256,
         mesh=mesh, method="pallas", precision=precision).compile()
-    assert _tpu_kernels(compiled.as_text()) == [PK.HIST_KERNEL_NAME]
-    assert compiled.memory_analysis().temp_size_in_bytes <= \
-        _HIGGS_TEMP_BYTES[precision]
+    text = compiled.as_text()
+    assert _tpu_kernels(text) == [PK.HIST_KERNEL_NAME]
+    params = dict(re.findall(r"%([\w.]+) = \S+ parameter\((\d)\)", text))
+    call = re.search(r"= \S+ custom-call\(([^)]*)\).*tpu_custom_call", text)
+    assert [params.get(a.strip().lstrip("%")) for a in
+            call.group(1).split(",")] == ["2", "0", "1"]  # bins, grad, hess
+    padded = -(-n // PK._CHUNK) * PK._CHUNK
+    moved = [ln.strip()[:200] for ln in text.splitlines()
+             if (m := re.search(r"= (.*?) (reduce|pad|concatenate|copy|"
+                                r"convert|fusion)\(", ln))
+             and any(e >= n and (e % n == 0 or e % padded == 0)
+                     for e in _elements(m.group(1)))]
+    assert moved == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+@pytest.mark.parametrize("precision", ["high", "fast"])
+def test_flat_kernel_splits_hi_lo_inside(one_chip, precision):
+    """At "high" the flat kernel splits each loaded block of grad and
+    hess itself: its lowered Mosaic body (the custom call's payload)
+    keeps both bf16 round-trips, truncf then extf, and subtracts each
+    hi part from its input, which an excess-precision fold would have
+    turned into exact zeros; at "fast" there is no split."""
+    import base64
+    import json
+    from jax._src.lib.mlir import ir
+    row = lambda dt: jax.ShapeDtypeStruct((1, 3 * PK._CHUNK + 5), dt,  # noqa: E731
+                                          sharding=one_chip)
+    text = PK._histogram_tpu_impl.lower(
+        row(jnp.int32), row(jnp.float32), row(jnp.float32), nbins=28 * 256,
+        precision=precision, interpret=False).as_text()
+    config = re.search(r'backend_config = "(.*?)"', text).group(1)
+    config = json.loads(re.sub(r"\\([0-9A-Fa-f]{2})",
+                               lambda m: chr(int(m.group(1), 16)), config))
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        body = str(ir.Module.parse(
+            base64.b64decode(config["custom_call_config"]["body"])))
+    vec = rf"vector<1x{PK._CHUNK}x"
+    trunc = re.findall(r"%(\d+) = \S*arith\.truncf\S* ?\(%(\d+)\) : "
+                       rf"\({vec}f32>\)", body)
+    ext = dict(re.findall(r"%(\d+) = \S*arith\.extf\S* ?\(%(\d+)\)", body))
+    sub = re.findall(r"arith\.subf\S* ?\(%(\d+), %(\d+)\)", body)
+    split = {(x, e) for e, t in ext.items() for t2, x in trunc if t == t2}
+    assert len(split) == (2 if precision == "high" else 0)
+    assert split <= set(sub)
 
 
 @pytest.mark.parametrize("precision", ["high", "fast"])
